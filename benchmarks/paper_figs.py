@@ -916,7 +916,8 @@ def measured_jax_collectives():
 import time, jax, jax.numpy as jnp
 from jax.sharding import PartitionSpec as P, NamedSharding
 from repro.core import collectives as C
-mesh = jax.make_mesh((8,), ('x',))
+from repro.launch.mesh import make_mesh
+mesh = make_mesh((8,), ('x',))
 n = 1 << 20
 full = jnp.arange(8 * n, dtype=jnp.float32)
 sharded = jax.device_put(full, NamedSharding(mesh, P('x')))
@@ -934,6 +935,7 @@ rs = C.make_reduce_scatter(mesh, 'x', 'bidi')
 print(f'collective.reduce_scatter_bidi_32MB_us,{t(rs, per_dev.reshape(-1)):.0f},measured 8dev')
 """
     env = dict(os.environ)
+    env["JAX_PLATFORMS"] = "cpu"   # host devices, even where a TPU is attached
     env["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
     env["PYTHONPATH"] = os.path.join(
         os.path.dirname(__file__), "..", "src"

@@ -1,5 +1,7 @@
 import os
 import sys
+# a host demo on 8 fake CPU devices, also where an accelerator is present
+os.environ["JAX_PLATFORMS"] = "cpu"
 os.environ.setdefault("XLA_FLAGS", "--xla_force_host_platform_device_count=8")
 sys.path.insert(0, os.path.join(os.path.dirname(__file__), "..", "src"))
 
@@ -19,18 +21,9 @@ import jax  # noqa: E402
 from repro.configs import (CollectiveConfig, MeshConfig, RunConfig, ShapeConfig,  # noqa: E402
                            TrainConfig, get_model_config, reduced)
 from repro.data import SyntheticPipeline  # noqa: E402
+from repro.launch.mesh import mesh_for  # noqa: E402
 from repro.runtime import init_state  # noqa: E402
 from repro.runtime.train_loop import jit_train_step  # noqa: E402
-
-
-class DemoMesh(MeshConfig):
-    @property
-    def shape(self):
-        return (2, 4)
-
-    @property
-    def axes(self):
-        return ("data", "model")
 
 
 def contention_report(model_name: str = "yi-9b") -> None:
@@ -78,11 +71,11 @@ def main():
         run = RunConfig(
             model=model,
             shape=ShapeConfig("t", "train", 128, 8),
-            mesh=DemoMesh(),
+            mesh=MeshConfig((2, 4), ("data", "model")),
             train=TrainConfig(steps=5, learning_rate=1e-2),
             collective=CollectiveConfig(fsdp_mode=mode, n_chains=2),
         )
-        mesh = jax.make_mesh((2, 4), ("data", "model"))
+        mesh = mesh_for(run.mesh)
         api, jstep = jit_train_step(run, mesh)
         state = init_state(run, mesh, jax.random.PRNGKey(0))
         pipe = SyntheticPipeline(model, run.shape)

@@ -163,15 +163,25 @@ SHAPES: dict[str, ShapeConfig] = {
 
 @dataclass(frozen=True)
 class MeshConfig:
-    multi_pod: bool = False
+    """The device mesh a run is laid out on: its shape and axis names.
+
+    Defaults to the production single-pod (16, 16) mesh;
+    ``MeshConfig.production(multi_pod=True)`` gives the (2, 16, 16) one. A
+    launcher that builds a different mesh records it here, so batch and
+    parameter sharding follow the mesh the step actually runs on.
+    """
+    shape: tuple[int, ...] = (16, 16)
+    axes: tuple[str, ...] = ("data", "model")
+
+    @classmethod
+    def production(cls, *, multi_pod: bool = False) -> "MeshConfig":
+        if multi_pod:
+            return cls((2, 16, 16), ("pod", "data", "model"))
+        return cls()
 
     @property
-    def shape(self) -> tuple[int, ...]:
-        return (2, 16, 16) if self.multi_pod else (16, 16)
-
-    @property
-    def axes(self) -> tuple[str, ...]:
-        return ("pod", "data", "model") if self.multi_pod else ("data", "model")
+    def multi_pod(self) -> bool:
+        return "pod" in self.axes
 
     @property
     def n_devices(self) -> int:
@@ -187,7 +197,7 @@ class CollectiveConfig:
     # fsdp_mode:
     #   "xla"   — parameters sharded, XLA inserts all-gather/reduce-scatter (baseline)
     #   "mcast" — explicit broadcast-composed allgather + bidirectional ring RS
-    #             on flat padded buckets (the paper's schedule)
+    #             on each weight along its sharded dim (the paper's schedule)
     fsdp_mode: str = "xla"
     # number of parallel broadcast chains M (paper Appendix A). 2 == the two
     # ring directions of a full-duplex ICI link (Fig. 1's two trees).

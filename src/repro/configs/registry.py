@@ -90,6 +90,6 @@ def make_run_config(arch: str, shape: str, *, multi_pod: bool = False, **train_k
     return RunConfig(
         model=get_model_config(arch),
         shape=get_shape(shape),
-        mesh=MeshConfig(multi_pod=multi_pod),
+        mesh=MeshConfig.production(multi_pod=multi_pod),
         train=TrainConfig(**train_kw) if train_kw else TrainConfig(),
     )

@@ -33,8 +33,6 @@ from jax import lax
 from jax.sharding import Mesh, NamedSharding
 from jax.sharding import PartitionSpec as P
 
-from repro import compat
-
 
 def _perm(p: int, direction: int):
     return [(i, (i + direction) % p) for i in range(p)]
@@ -50,7 +48,7 @@ def pipelined_broadcast_local(x: jax.Array, axis: str, *, root: int = 0,
 
     Per-link bytes: N * (1 + (P-2)/C); schedule time constant in P for C >> P.
     """
-    p = compat.axis_size(axis)
+    p = lax.axis_size(axis)
     idx = lax.axis_index(axis)
     dist = (idx - root) % p
     n = x.shape[0]
@@ -77,9 +75,9 @@ def pipelined_broadcast_local(x: jax.Array, axis: str, *, root: int = 0,
 
 
 def ring_allgather_local(x: jax.Array, axis: str, *, direction: int = +1) -> jax.Array:
-    """Unidirectional ring allgather: P-1 forwarding steps. x: (n,) shard.
-    Returns (P*n,) in rank order."""
-    p = compat.axis_size(axis)
+    """Unidirectional ring allgather: P-1 forwarding steps. x: (n, ...)
+    shard. Returns (P*n, ...) in rank order."""
+    p = lax.axis_size(axis)
     idx = lax.axis_index(axis)
     out = jnp.zeros((p,) + x.shape, x.dtype).at[idx].set(x)
 
@@ -95,16 +93,17 @@ def ring_allgather_local(x: jax.Array, axis: str, *, direction: int = +1) -> jax
 
 
 def bidi_ring_allgather_local(x: jax.Array, axis: str) -> jax.Array:
-    """Bidirectional ring allgather (Fig. 1's two trees): each half-shard
-    travels one direction; both directions are concurrently active, so the
-    completion time halves on full-duplex links. x: (n,), n even."""
-    p = compat.axis_size(axis)
+    """Bidirectional ring allgather (Fig. 1's two trees): each half of the
+    shard's leading dim travels one direction; both directions are
+    concurrently active, so the completion time halves on full-duplex links.
+    x: (n, ...) shard. Returns (P*n, ...) in rank order."""
+    p = lax.axis_size(axis)
     idx = lax.axis_index(axis)
     n = x.shape[0]
     half = n // 2
     xa, xb = x[:half], x[half:]
-    out_a = jnp.zeros((p, half), x.dtype).at[idx].set(xa)
-    out_b = jnp.zeros((p, n - half), x.dtype).at[idx].set(xb)
+    out_a = jnp.zeros((p,) + xa.shape, x.dtype).at[idx].set(xa)
+    out_b = jnp.zeros((p,) + xb.shape, x.dtype).at[idx].set(xb)
 
     def step(carry, s):
         oa, ob, ca, cb = carry
@@ -117,7 +116,7 @@ def bidi_ring_allgather_local(x: jax.Array, axis: str) -> jax.Array:
     (out_a, out_b, _, _), _ = lax.scan(
         step, (out_a, out_b, xa, xb), jnp.arange(p - 1)
     )
-    return jnp.concatenate([out_a, out_b], axis=-1).reshape(p * n)
+    return jnp.concatenate([out_a, out_b], axis=1).reshape((p * n,) + x.shape[1:])
 
 
 def bcast_allgather_local(x: jax.Array, axis: str, *, n_chains: int) -> jax.Array:
@@ -127,7 +126,7 @@ def bcast_allgather_local(x: jax.Array, axis: str, *, n_chains: int) -> jax.Arra
 
     M = P is the fully-parallel degenerate case == ring allgather.
     """
-    p = compat.axis_size(axis)
+    p = lax.axis_size(axis)
     assert p % n_chains == 0, (p, n_chains)
     rounds = p // n_chains
     idx = lax.axis_index(axis)
@@ -156,7 +155,7 @@ def bcast_allgather_local(x: jax.Array, axis: str, *, n_chains: int) -> jax.Arra
 def ring_reduce_scatter_local(x: jax.Array, axis: str, *, direction: int = +1) -> jax.Array:
     """Ring reduce-scatter. x: (P*n,) full per-device contribution; returns
     (n,) — the sum over devices of shard idx."""
-    p = compat.axis_size(axis)
+    p = lax.axis_size(axis)
     idx = lax.axis_index(axis)
     n = x.shape[0] // p
     xv = x.reshape((p, n) + x.shape[1:])
@@ -173,7 +172,7 @@ def ring_reduce_scatter_local(x: jax.Array, axis: str, *, direction: int = +1) -
 
 def bidi_ring_reduce_scatter_local(x: jax.Array, axis: str) -> jax.Array:
     """Both directions carry half the shard each."""
-    p = compat.axis_size(axis)
+    p = lax.axis_size(axis)
     n = x.shape[0] // p
     half = n // 2
     xv = x.reshape(p, n)
@@ -192,7 +191,7 @@ def concurrent_ag_rs_local(ag_shard: jax.Array, rs_full: jax.Array, axis: str):
     (counter-clockwise). The two ppermute streams use opposite ICI directions,
     so — like the paper's {AG_mc, RS_inc} pairing — they do not share a link
     bottleneck. Returns (ag_full (P*n,), rs_shard (m,))."""
-    p = compat.axis_size(axis)
+    p = lax.axis_size(axis)
     idx = lax.axis_index(axis)
     n = ag_shard.shape[0]
     m = rs_full.shape[0] // p
@@ -238,7 +237,7 @@ def make_allgather(mesh: Mesh, axis: str, mode: str = "bidi", *, n_chains: int |
             n_chains=n_chains or mesh.shape[axis],
         ),
     }[mode]
-    sm = compat.shard_map(
+    sm = jax.shard_map(
         local, mesh=mesh, in_specs=P(axis), out_specs=P(), check_vma=False
     )
     return jax.jit(sm)
@@ -250,7 +249,7 @@ def make_reduce_scatter(mesh: Mesh, axis: str, mode: str = "bidi"):
         "ring": functools.partial(ring_reduce_scatter_local, axis=axis),
         "bidi": functools.partial(bidi_ring_reduce_scatter_local, axis=axis),
     }[mode]
-    sm = compat.shard_map(
+    sm = jax.shard_map(
         local, mesh=mesh, in_specs=P(), out_specs=P(axis), check_vma=False
     )
     return jax.jit(sm)
@@ -261,5 +260,5 @@ def make_broadcast(mesh: Mesh, axis: str, *, root: int = 0, n_chunks: int = 8):
     local = functools.partial(
         pipelined_broadcast_local, axis=axis, root=root, n_chunks=n_chunks
     )
-    sm = compat.shard_map(local, mesh=mesh, in_specs=P(axis), out_specs=P(), check_vma=False)
+    sm = jax.shard_map(local, mesh=mesh, in_specs=P(axis), out_specs=P(), check_vma=False)
     return jax.jit(sm)

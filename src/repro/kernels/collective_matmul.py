@@ -23,8 +23,6 @@ from jax import lax
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro import compat
-
 
 def _matmul_kernel(x_ref, w_ref, o_ref, acc_ref, *, nk: int):
     @pl.when(pl.program_id(2) == 0)
@@ -73,7 +71,7 @@ def allgather_matmul_local(x_shard: jax.Array, w: jax.Array, axis: str, *,
 
     x_shard: (m_loc, k) local shard; returns (P*m_loc, n) (replicated value).
     """
-    p = compat.axis_size(axis)
+    p = lax.axis_size(axis)
     idx = lax.axis_index(axis)
     mm = (
         functools.partial(matmul_pallas, bm=bm, bk=bk, bn=bn)
@@ -100,7 +98,7 @@ def make_allgather_matmul(mesh, axis: str, **kw):
     from jax.sharding import PartitionSpec as P
 
     local = functools.partial(allgather_matmul_local, axis=axis, **kw)
-    sm = compat.shard_map(
+    sm = jax.shard_map(
         local, mesh=mesh, in_specs=(P(axis, None), P(None, None)),
         out_specs=P(None, None), check_vma=False,
     )

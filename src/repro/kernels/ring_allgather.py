@@ -32,17 +32,6 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 
-def _compiler_params(**kw):
-    """jax >= 0.5 renamed TPUCompilerParams -> CompilerParams; resolve
-    whichever this version exposes and fail loudly if neither exists."""
-    cls = getattr(pltpu, "CompilerParams",
-                  getattr(pltpu, "TPUCompilerParams", None))
-    assert cls is not None, (
-        "pallas TPU exposes neither CompilerParams nor TPUCompilerParams — "
-        "a new rename needs handling here")
-    return cls(**kw)
-
-
 def ring_allgather_tpu(x_shard: jax.Array, *, axis_name: str = "ring",
                        n_devices: int) -> jax.Array:
     """TPU-only: run inside shard_map over ``axis_name``. x_shard (rows, cols)
@@ -50,19 +39,33 @@ def ring_allgather_tpu(x_shard: jax.Array, *, axis_name: str = "ring",
     rows, cols = x_shard.shape
     out_shape = jax.ShapeDtypeStruct((n_devices, rows, cols), x_shard.dtype)
 
-    def kernel(x_ref, out_ref, send_sem, recv_sem):
+    def kernel(x_ref, out_ref, copy_sem, send_sem, recv_sem):
         my_id = jax.lax.axis_index(axis_name)
-        # install own shard
-        out_ref[my_id] = x_ref[...]
         step = pl.program_id(0)
+
         right = jax.lax.rem(my_id + 1, n_devices)
+        left = jax.lax.rem(my_id - 1 + n_devices, n_devices)
+
+        @pl.when(step == 0)
+        def _():
+            # install own shard: both refs live in HBM (ANY), which only a
+            # DMA may touch
+            pltpu.async_copy(x_ref, out_ref.at[my_id], copy_sem).wait()
+            # no neighbour may write into out_ref before its owner is in
+            # the kernel
+            barrier = pltpu.get_barrier_semaphore()
+            for nbr in (left, right):
+                pltpu.semaphore_signal(barrier, 1, device_id=nbr,
+                                       device_id_type=pltpu.DeviceIdType.LOGICAL)
+            pltpu.semaphore_wait(barrier, 2)
+
         src = jax.lax.rem(my_id - step + n_devices, n_devices)
         rdma = pltpu.make_async_remote_copy(
             src_ref=out_ref.at[src],
             dst_ref=out_ref.at[src],
             send_sem=send_sem,
             recv_sem=recv_sem,
-            device_id=(right,),
+            device_id=right,
             device_id_type=pltpu.DeviceIdType.LOGICAL,
         )
         rdma.start()
@@ -71,11 +74,11 @@ def ring_allgather_tpu(x_shard: jax.Array, *, axis_name: str = "ring",
     return pl.pallas_call(
         kernel,
         grid=(n_devices - 1,),
-        in_specs=[pl.BlockSpec(memory_space=pltpu.ANY)],
-        out_specs=pl.BlockSpec(memory_space=pltpu.ANY),
+        in_specs=[pl.BlockSpec(memory_space=pl.ANY)],
+        out_specs=pl.BlockSpec(memory_space=pl.ANY),
         out_shape=out_shape,
-        scratch_shapes=[pltpu.SemaphoreType.DMA, pltpu.SemaphoreType.DMA],
-        compiler_params=_compiler_params(collective_id=0),
+        scratch_shapes=[pltpu.SemaphoreType.DMA] * 3,
+        compiler_params=pltpu.CompilerParams(collective_id=0),
     )(x_shard).reshape(n_devices * rows, cols)
 
 

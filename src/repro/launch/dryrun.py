@@ -30,13 +30,14 @@ import jax.numpy as jnp  # noqa: E402
 
 from repro.configs import (  # noqa: E402
     SHAPES,
+    MeshConfig,
     cell_supported,
     get_model_config,
     iter_cells,
     make_run_config,
 )
 from repro.launch import analytic_costs, hlo_stats  # noqa: E402
-from repro.launch.mesh import describe, make_production_mesh  # noqa: E402
+from repro.launch.mesh import describe, mesh_for  # noqa: E402
 from repro.models import batch_dims  # noqa: E402
 
 
@@ -118,9 +119,8 @@ def run_cell(arch: str, shape_name: str, multi_pod: bool, *,
     )
     if mesh_shape is not None:
         assert not multi_pod, "mesh regrouping is a single-pod perf knob"
-        mesh = jax.make_mesh(mesh_shape, ("data", "model"))
-    else:
-        mesh = make_production_mesh(multi_pod=multi_pod)
+        run = run.replace(mesh=MeshConfig(tuple(mesh_shape), ("data", "model")))
+    mesh = mesh_for(run.mesh)
     n_dev = mesh.devices.size
     rec: dict = {
         "arch": arch, "shape": shape_name, "multi_pod": multi_pod,

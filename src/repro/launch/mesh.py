@@ -1,23 +1,31 @@
-"""Production mesh construction.
+"""Mesh construction: every mesh of the repo is built here.
 
-make_production_mesh() is a FUNCTION (not module-level state) so importing
-this module never touches jax device initialization. The dry-run entry point
+These are FUNCTIONS (not module-level state) so importing this module never
+touches jax device initialization. The dry-run entry point
 (launch/dryrun.py) sets XLA_FLAGS for 512 placeholder devices before any jax
 import; everything else (tests, benches) sees the real device count.
 """
 from __future__ import annotations
 
+from typing import Sequence
+
 import jax
+from jax.sharding import AxisType
+
+from repro.configs.base import MeshConfig
 
 
-def make_production_mesh(*, multi_pod: bool = False) -> jax.sharding.Mesh:
-    shape = (2, 16, 16) if multi_pod else (16, 16)
-    axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return jax.make_mesh(shape, axes)
+def make_mesh(shape: Sequence[int], axes: Sequence[str], *,
+              devices: Sequence | None = None) -> jax.sharding.Mesh:
+    """A mesh with Auto axes. ``jax.make_mesh`` defaults to Explicit axes,
+    under which GSPMD no longer propagates the shardings the model relies on:
+    ``with_sharding_constraint`` and sharded contractions are refused."""
+    return jax.make_mesh(tuple(shape), tuple(axes),
+                         axis_types=(AxisType.Auto,) * len(axes), devices=devices)
 
 
-def make_mesh_for(shape: tuple[int, ...], axes: tuple[str, ...]) -> jax.sharding.Mesh:
-    return jax.make_mesh(shape, axes)
+def mesh_for(cfg: MeshConfig, *, devices: Sequence | None = None) -> jax.sharding.Mesh:
+    return make_mesh(cfg.shape, cfg.axes, devices=devices)
 
 
 def describe(mesh: jax.sharding.Mesh) -> dict:

@@ -15,6 +15,36 @@ import argparse
 import dataclasses
 
 import jax
+from jax.sharding import Mesh
+
+from repro.configs import (MeshConfig, RunConfig, ShapeConfig, TrainConfig,
+                           make_run_config, reduced)
+from repro.data import SyntheticPipeline
+from repro.launch.compile_cache import enable_compile_cache
+from repro.launch.mesh import mesh_for
+from repro.runtime import init_state, jit_train_step, make_train_step
+from repro.runtime.fault import TrainSupervisor
+
+
+def train(run: RunConfig, mesh: Mesh | None):
+    """Initialise from ``run.train.seed`` and take ``run.train.steps`` steps
+    under the checkpoint/restart supervisor. ``mesh`` must match ``run.mesh``;
+    None runs on the default device. Returns (state, history, supervisor)."""
+    if mesh is not None:
+        assert dict(mesh.shape) == dict(zip(run.mesh.axes, run.mesh.shape)), (
+            mesh.shape, run.mesh)
+        api, step_fn = jit_train_step(run, mesh)
+    else:
+        api, ctx, step_raw = make_train_step(run, None)
+        step_fn = jax.jit(step_raw)
+
+    state = init_state(run, mesh, jax.random.PRNGKey(run.train.seed))
+    sup = TrainSupervisor(
+        step_fn=step_fn, pipeline=SyntheticPipeline(run.model, run.shape),
+        ckpt_dir=run.train.checkpoint_dir, ckpt_every=run.train.checkpoint_every,
+    )
+    state, history = sup.run(state, run.train.steps)
+    return state, history, sup
 
 
 def main():
@@ -37,14 +67,9 @@ def main():
     ap.add_argument("--distributed", action="store_true")
     args = ap.parse_args()
 
+    enable_compile_cache()
     if args.distributed:
         jax.distributed.initialize()
-
-    from repro.configs import (SHAPES, ShapeConfig, TrainConfig, get_model_config,
-                               make_run_config, reduced)
-    from repro.data import SyntheticPipeline
-    from repro.runtime import init_state, make_train_step
-    from repro.runtime.fault import TrainSupervisor
 
     run = make_run_config(args.arch, args.shape, multi_pod=args.multi_pod)
     model = run.model
@@ -68,33 +93,18 @@ def main():
 
     mesh = None
     if args.mesh == "production":
-        from repro.launch.mesh import make_production_mesh
-
-        mesh = make_production_mesh(multi_pod=args.multi_pod)
+        mesh = mesh_for(run.mesh)
     elif jax.device_count() > 1:
         n = jax.device_count()
         dp = max(1, n // 2)
-        mesh = jax.make_mesh((dp, n // dp), ("data", "model"))
+        run = run.replace(mesh=MeshConfig((dp, n // dp), ("data", "model")))
+        mesh = mesh_for(run.mesh)
 
     print(f"[train] {model.name} shape={shape.name} B={shape.global_batch} "
           f"S={shape.seq_len} devices={jax.device_count()} "
           f"fsdp={args.fsdp_mode}", flush=True)
 
-    if mesh is not None:
-        from repro.runtime.train_loop import jit_train_step
-
-        api, step_fn = jit_train_step(run, mesh)
-    else:
-        api, ctx, step_raw = make_train_step(run, None)
-        step_fn = jax.jit(step_raw)
-
-    state = init_state(run, mesh, jax.random.PRNGKey(run.train.seed))
-    pipe = SyntheticPipeline(model, shape)
-    sup = TrainSupervisor(
-        step_fn=step_fn, pipeline=pipe, ckpt_dir=args.ckpt_dir,
-        ckpt_every=args.ckpt_every,
-    )
-    state, history = sup.run(state, args.steps)
+    state, history, sup = train(run, mesh)
     for h in history:
         if h["step"] % args.log_every == 0 or h["step"] == args.steps - 1:
             print(f"step {h['step']:5d} loss {h['loss']:.4f} "

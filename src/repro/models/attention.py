@@ -20,8 +20,6 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
-from repro import compat
-
 NEG_INF = -1e30
 
 
@@ -281,7 +279,7 @@ def flash_decode_attention(
         return out.reshape(bl, h, hd).astype(q_l.dtype)
 
     dp = P(batch_axes)
-    return compat.shard_map(
+    return jax.shard_map(
         shard_fn,
         mesh=mesh,
         in_specs=(

@@ -14,6 +14,8 @@ import time
 from dataclasses import dataclass, field
 from typing import Any, Callable
 
+import jax
+
 from repro.checkpoint import latest_step, restore, save
 
 
@@ -77,7 +79,8 @@ class TrainSupervisor:
                 if self.injector:
                     self.injector.check(step)
                 t0 = time.monotonic()
-                state, metrics = self.step_fn(state, batch)
+                # block: dt is the step's device time, not its dispatch
+                state, metrics = jax.block_until_ready(self.step_fn(state, batch))
                 dt = time.monotonic() - t0
                 self.monitor.observe(step, dt)
                 history.append({"step": step, "dt": dt, **{

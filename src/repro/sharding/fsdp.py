@@ -26,7 +26,6 @@ from jax.sharding import Mesh
 from jax.sharding import PartitionSpec as P
 
 from repro.configs.base import CollectiveConfig, MeshConfig
-from repro import compat
 from repro.core import collectives as C
 from repro.sharding.specs import _leaf_spec, dp_axes
 
@@ -40,12 +39,15 @@ def _remove_axis(entry, axis):
     return rest if len(rest) > 1 else (rest[0] if rest else None)
 
 
-def _ag_local(flat, axis, mode, n_chains):
-    if mode == "bidi" and flat.shape[0] % 2 == 0:
-        return C.bidi_ring_allgather_local(flat, axis)
+def _ag_local(x, axis, mode, n_chains):
+    """Gather the leading dim of ``x`` over ``axis``. The shard keeps its
+    shape: flattening it to 1-D costs the TPU compiler about a minute per
+    large weight, to lay the gathered result out in 2-D again."""
+    if mode == "bidi" and x.shape[0] % 2 == 0:
+        return C.bidi_ring_allgather_local(x, axis)
     if mode == "bcast":
-        return C.bcast_allgather_local(flat, axis, n_chains=n_chains)
-    return C.ring_allgather_local(flat, axis)
+        return C.bcast_allgather_local(x, axis, n_chains=n_chains)
+    return C.ring_allgather_local(x, axis)
 
 
 def gather_dim(x: jax.Array, spec: P, axis: str, dim: int, mesh: Mesh,
@@ -57,13 +59,10 @@ def gather_dim(x: jax.Array, spec: P, axis: str, dim: int, mesh: Mesh,
     p = mesh.shape[axis]
 
     def local(xl):
-        moved = jnp.moveaxis(xl, dim, 0)
-        flat = moved.reshape(-1)
-        full = _ag_local(flat, axis, mode, min(n_chains, p))
-        out = full.reshape((p * moved.shape[0],) + moved.shape[1:])
-        return jnp.moveaxis(out, 0, dim)
+        full = _ag_local(jnp.moveaxis(xl, dim, 0), axis, mode, min(n_chains, p))
+        return jnp.moveaxis(full, 0, dim)
 
-    y = compat.shard_map(
+    y = jax.shard_map(
         local, mesh=mesh, in_specs=spec, out_specs=out_spec, check_vma=False
     )(x)
     return y, out_spec

@@ -22,9 +22,12 @@ def run_multidev(code: str, n_devices: int = 8, timeout: int = 300):
     """Run a python snippet in a subprocess with N fake CPU devices.
 
     XLA_FLAGS must NOT be set globally (smoke tests see 1 device), so
-    multi-device tests run in their own process.
+    multi-device tests run in their own process. JAX_PLATFORMS pins the
+    child to the CPU: where a TPU is attached, a parent that imported jax
+    holds it.
     """
     env = dict(os.environ)
+    env["JAX_PLATFORMS"] = "cpu"
     env["XLA_FLAGS"] = f"--xla_force_host_platform_device_count={n_devices}"
     env["PYTHONPATH"] = SRC + os.pathsep + env.get("PYTHONPATH", "")
     res = subprocess.run(

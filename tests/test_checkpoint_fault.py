@@ -90,23 +90,13 @@ from repro.runtime import init_state
 from repro.runtime.elastic import reshard_state, scale_plan
 from repro.checkpoint import save, restore
 from repro.runtime.train_loop import state_pspecs
+from repro.launch.mesh import mesh_for
 from jax.sharding import NamedSharding, PartitionSpec as P
 
-class M24(MeshConfig):
-    @property
-    def shape(self): return (2, 4)
-    @property
-    def axes(self): return ('data', 'model')
-
-class M42(MeshConfig):
-    @property
-    def shape(self): return (4, 2)
-    @property
-    def axes(self): return ('data', 'model')
-
 cfg = reduced(get_model_config('smollm-135m'))
-run1 = RunConfig(model=cfg, shape=ShapeConfig('t','train',32,8), mesh=M24())
-mesh1 = jax.make_mesh((2,4), ('data','model'))
+run1 = RunConfig(model=cfg, shape=ShapeConfig('t','train',32,8),
+                 mesh=MeshConfig((2, 4), ('data', 'model')))
+mesh1 = mesh_for(run1.mesh)
 state = init_state(run1, mesh1, jax.random.PRNGKey(0))
 import tempfile, os
 
@@ -114,8 +104,8 @@ d = tempfile.mkdtemp()
 save(state, d, 5)
 
 # restore into a (4,2) mesh — elastic rescale
-run2 = run1.replace(mesh=M42())
-mesh2 = jax.make_mesh((4,2), ('data','model'))
+run2 = run1.replace(mesh=MeshConfig((4, 2), ('data', 'model')))
+mesh2 = mesh_for(run2.mesh)
 specs = state_pspecs(run2, mesh2)
 sh = jax.tree.map(lambda s: NamedSharding(mesh2, s), specs, is_leaf=lambda x: isinstance(x, P))
 like = jax.tree.map(lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype), state)

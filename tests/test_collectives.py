@@ -14,7 +14,8 @@ import pytest
 import jax, jax.numpy as jnp, numpy as np
 from jax.sharding import PartitionSpec as P, NamedSharding
 from repro.core import collectives as C
-mesh = jax.make_mesh((8,), ('x',))
+from repro.launch.mesh import make_mesh
+mesh = make_mesh((8,), ('x',))
 n = 64
 full = jnp.arange(8 * n, dtype=jnp.float32)
 sharded = jax.device_put(full, NamedSharding(mesh, P('x')))
@@ -24,6 +25,13 @@ for mode in ['ring', 'bidi']:
 for m in [1, 2, 4, 8]:
     out = C.make_allgather(mesh, 'x', 'bcast', n_chains=m)(sharded)
     assert np.allclose(np.asarray(out), np.asarray(full)), m
+# 2-D shards gather along their leading dim (the FSDP weight layout)
+full2 = full.reshape(8 * 4, n // 4)
+sharded2 = jax.device_put(full2, NamedSharding(mesh, P('x', None)))
+for mode in ['ring', 'bidi', 'bcast']:
+    out = C.make_allgather(mesh, 'x', mode)(sharded2)
+    assert out.shape == full2.shape, (mode, out.shape)
+    assert np.array_equal(np.asarray(out), np.asarray(full2)), mode
 print('ok')
 """
     )
@@ -34,22 +42,22 @@ def test_reduce_scatter_and_concurrent(multidev):
         """
 import jax, jax.numpy as jnp, numpy as np
 from jax.sharding import PartitionSpec as P, NamedSharding
-from repro import compat
 from repro.core import collectives as C
-mesh = jax.make_mesh((8,), ('x',))
+from repro.launch.mesh import make_mesh
+mesh = make_mesh((8,), ('x',))
 n = 64
 full = jnp.arange(8 * n, dtype=jnp.float32)
 per_dev = jnp.stack([full * (i + 1) for i in range(8)])
 for mode, local in [('ring', C.ring_reduce_scatter_local),
                     ('bidi', C.bidi_ring_reduce_scatter_local)]:
-    sm = compat.shard_map(lambda x: local(x[0], 'x'), mesh=mesh,
-                       in_specs=P('x'), out_specs=P('x'), check_vma=False)
+    sm = jax.shard_map(lambda x: local(x[0], 'x'), mesh=mesh,
+                    in_specs=P('x'), out_specs=P('x'), check_vma=False)
     out = sm(per_dev)
     expect = np.asarray(full).reshape(8, n) * 36
     assert np.allclose(np.asarray(out), expect.reshape(-1)), mode
 # concurrent AG+RS (direction split)
 sharded = jax.device_put(full, NamedSharding(mesh, P('x')))
-agf, rss = jax.jit(lambda a, r: compat.shard_map(
+agf, rss = jax.jit(lambda a, r: jax.shard_map(
     lambda aa, rr: C.concurrent_ag_rs_local(aa, rr[0], 'x'),
     mesh=mesh, in_specs=(P('x'), P('x')), out_specs=(P(), P('x')),
     check_vma=False)(a, r))(sharded, per_dev.reshape(8, 8 * n))
@@ -66,7 +74,8 @@ def test_pipelined_broadcast_roots_and_chunks(multidev):
 import jax, jax.numpy as jnp, numpy as np
 from jax.sharding import PartitionSpec as P, NamedSharding
 from repro.core import collectives as C
-mesh = jax.make_mesh((8,), ('x',))
+from repro.launch.mesh import make_mesh
+mesh = make_mesh((8,), ('x',))
 n = 64
 full = jnp.arange(8 * n, dtype=jnp.float32)
 sharded = jax.device_put(full, NamedSharding(mesh, P('x')))
@@ -88,7 +97,8 @@ import jax, jax.numpy as jnp, numpy as np
 from jax.sharding import PartitionSpec as P, NamedSharding
 from repro.core import collectives as C
 
-mesh = jax.make_mesh((8,), ('x',))
+from repro.launch.mesh import make_mesh
+mesh = make_mesh((8,), ('x',))
 n = 32
 full = jnp.arange(8 * n, dtype=jnp.float32)
 sharded = jax.device_put(full, NamedSharding(mesh, P('x')))

@@ -32,7 +32,7 @@ def _assert_tree(topo, root_name, member_names, links):
     in-edge and is reachable from the root."""
     children = {}
     in_deg = {}
-    nodes = set()
+    nodes = {root_name}   # a root-only group is a tree with no links
     for link in links:
         children.setdefault(link.src, []).append(link.dst)
         in_deg[link.dst] = in_deg.get(link.dst, 0) + 1

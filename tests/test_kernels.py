@@ -1,4 +1,5 @@
 """Pallas kernel sweeps vs pure-jnp oracles (interpret=True on CPU)."""
+import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
@@ -91,7 +92,8 @@ def test_collective_matmul_multidev(multidev):
 import jax, jax.numpy as jnp, numpy as np
 from jax.sharding import PartitionSpec as P, NamedSharding
 from repro.kernels import ops, ref
-mesh = jax.make_mesh((8,), ('x',))
+from repro.launch.mesh import make_mesh
+mesh = make_mesh((8,), ('x',))
 rng = np.random.default_rng(1)
 x = jnp.asarray(rng.standard_normal((8*128, 256)), jnp.float32)
 w = jnp.asarray(rng.standard_normal((256, 128)), jnp.float32)
@@ -121,8 +123,6 @@ def test_pool_scan_kernel_bit_exact_vs_numpy_twin(cfg):
     jax-free numpy twin (the engine's production inner path) in f64 — both
     run the identical per-lane op sequence, so equality is exact, not
     approximate."""
-    from jax.experimental import enable_x64
-
     from repro.kernels import pool
     from repro.kernels.pool_np import pool_completion_rows_np
 
@@ -130,7 +130,7 @@ def test_pool_scan_kernel_bit_exact_vs_numpy_twin(cfg):
     rng = np.random.default_rng(rows * 1000 + n)
     a = np.sort(rng.uniform(0.0, 10.0, (rows, n)), axis=1)
     d_np, m_np = pool_completion_rows_np(a, w, s, staging)
-    with enable_x64():
+    with jax.enable_x64(True):
         d_j, m_j = pool.pool_completion_rows(jnp.asarray(a), w, s, staging)
         assert np.asarray(d_j).dtype == np.float64
         np.testing.assert_array_equal(np.asarray(d_j), d_np)

@@ -18,7 +18,8 @@ import pytest
 import jax, jax.numpy as jnp
 from jax.sharding import PartitionSpec as P, NamedSharding
 from repro.launch.hlo_stats import collective_stats
-mesh = jax.make_mesh((2, 4), ('data', 'model'))
+from repro.launch.mesh import make_mesh
+mesh = make_mesh((2, 4), ('data', 'model'))
 
 def f(x, w):
     y = x @ w                          # contraction over sharded dim -> AR/RS
@@ -44,7 +45,8 @@ def test_loop_scaled_collectives(multidev):
 import jax, jax.numpy as jnp
 from jax.sharding import PartitionSpec as P, NamedSharding
 from repro.launch.hlo_stats import collective_stats
-mesh = jax.make_mesh((8,), ('x',))
+from repro.launch.mesh import make_mesh
+mesh = make_mesh((8,), ('x',))
 
 def f(x, ws):
     def body(c, w):

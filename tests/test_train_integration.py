@@ -56,23 +56,19 @@ def test_fsdp_modes_bitwise_equal(multidev):
 import jax, dataclasses
 from repro.configs import (CollectiveConfig, MeshConfig, RunConfig, ShapeConfig,
                            TrainConfig, get_model_config, reduced)
+from repro.launch.mesh import mesh_for
 from repro.runtime import init_state
 from repro.runtime.train_loop import jit_train_step
 from repro.data import SyntheticPipeline
 
-class SmallMesh(MeshConfig):
-    @property
-    def shape(self): return (2, 4)
-    @property
-    def axes(self): return ('data', 'model')
-
 cfg = reduced(get_model_config('smollm-135m'))
 out = {}
 for mode in ['xla', 'mcast', 'mcast_bcast']:
-    run = RunConfig(model=cfg, shape=ShapeConfig('t','train',64,4), mesh=SmallMesh(),
+    run = RunConfig(model=cfg, shape=ShapeConfig('t','train',64,4),
+                    mesh=MeshConfig((2, 4), ('data', 'model')),
                     train=TrainConfig(steps=5),
                     collective=CollectiveConfig(fsdp_mode=mode, n_chains=2))
-    mesh = jax.make_mesh((2,4), ('data','model'))
+    mesh = mesh_for(run.mesh)
     api, jstep = jit_train_step(run, mesh)
     state = init_state(run, mesh, jax.random.PRNGKey(0))
     pipe = SyntheticPipeline(cfg, run.shape)
@@ -94,20 +90,15 @@ def test_moe_train_multidev(multidev):
 import jax
 from repro.configs import (MeshConfig, RunConfig, ShapeConfig, TrainConfig,
                            get_model_config, reduced)
+from repro.launch.mesh import mesh_for
 from repro.runtime import init_state
 from repro.runtime.train_loop import jit_train_step
 from repro.data import SyntheticPipeline
 
-class SmallMesh(MeshConfig):
-    @property
-    def shape(self): return (2, 4)
-    @property
-    def axes(self): return ('data', 'model')
-
 cfg = reduced(get_model_config('deepseek-moe-16b'))
-run = RunConfig(model=cfg, shape=ShapeConfig('t','train',64,4), mesh=SmallMesh(),
-                train=TrainConfig(steps=2))
-mesh = jax.make_mesh((2,4), ('data','model'))
+run = RunConfig(model=cfg, shape=ShapeConfig('t','train',64,4),
+                mesh=MeshConfig((2, 4), ('data', 'model')), train=TrainConfig(steps=2))
+mesh = mesh_for(run.mesh)
 api, jstep = jit_train_step(run, mesh)
 state = init_state(run, mesh, jax.random.PRNGKey(0))
 pipe = SyntheticPipeline(cfg, run.shape)

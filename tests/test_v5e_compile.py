@@ -1,0 +1,106 @@
+"""Compiles of the main path for a described TPU v5e; nothing runs.
+
+The TPU compiler refuses what the CPU backend and interpret mode accept:
+memory spaces, tiling, programs that do not fit the device. These tests run
+that compiler on programs of real widths. The topology is described only
+inside the module fixture (only one process at a time may load the TPU
+library, and every test worker imports this file), and the persistent
+compile cache is off around them: entries compiled for a described chip
+cannot be read back without one.
+"""
+import dataclasses
+
+import jax
+import jax.numpy as jnp
+import pytest
+from jax.sharding import NamedSharding, SingleDeviceSharding
+from jax.sharding import PartitionSpec as P
+
+from repro.configs import (CollectiveConfig, MeshConfig, RunConfig, ShapeConfig,
+                           TrainConfig, get_model_config)
+from repro.kernels.ring_allgather import ring_allgather_tpu
+from repro.launch.mesh import make_mesh, mesh_for
+from repro.models import batch_dims, build_model
+from repro.runtime.train_loop import abstract_state, make_train_step
+from repro.sharding.fsdp import make_param_gather
+from repro.sharding.specs import param_pspecs
+
+V5E_HBM_BYTES = 16 * 2**30
+DATA4 = MeshConfig((4, 1), ("data", "model"))
+
+
+@pytest.fixture(scope="module")
+def topo():
+    from jax.experimental import topologies
+    from jax.experimental.compilation_cache import compilation_cache
+
+    with pytest.MonkeyPatch.context() as mp:
+        mp.setenv("TPU_LOG_DIR", "disabled")
+        try:
+            desc = topologies.get_topology_desc(platform="tpu", topology_name="v5e:2x2")
+        except Exception as e:
+            pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+        was = jax.config.jax_enable_compilation_cache
+        jax.config.update("jax_enable_compilation_cache", False)
+        compilation_cache.reset_cache()
+        try:
+            yield desc
+        finally:
+            jax.config.update("jax_enable_compilation_cache", was)
+            compilation_cache.reset_cache()
+
+
+def _with_sharding(tree, sharding):
+    return jax.tree.map(
+        lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=sharding), tree)
+
+
+def test_smollm_train_step_fits_one_chip(topo):
+    """smollm-135m at published widths, cut to 2 layers, B=8 S=2048."""
+    one = SingleDeviceSharding(topo.devices[0])
+    model = dataclasses.replace(get_model_config("smollm-135m"), num_layers=2)
+    run = RunConfig(model=model, shape=ShapeConfig("t", "train", 2048, 8),
+                    train=TrainConfig(checkpoint_every=0))
+    _, _, step = make_train_step(run, None)
+    batch = {k: jax.ShapeDtypeStruct(v, jnp.int32, sharding=one)
+             for k, v in batch_dims(model, run.shape).items()}
+    compiled = jax.jit(step).lower(
+        _with_sharding(abstract_state(run), one), batch).compile()
+    mem = compiled.memory_analysis()
+    used = (mem.argument_size_in_bytes + mem.output_size_in_bytes
+            + mem.temp_size_in_bytes - mem.alias_size_in_bytes)
+    assert 0 < used < V5E_HBM_BYTES, mem
+
+
+@pytest.mark.parametrize("mode", ["mcast", "mcast_bcast"])
+def test_yi9b_layer_gather_on_data4(topo, mode):
+    """One layer's FSDP weight gather at yi-9b widths on the 2x2 host: the
+    paper's schedules lower to collective-permutes and leave every weight
+    whole on each device."""
+    mesh = mesh_for(DATA4, devices=topo.devices)
+    model = dataclasses.replace(get_model_config("yi-9b"), num_layers=1)
+    params = jax.eval_shape(build_model(model).init_params, jax.random.PRNGKey(0))
+    layer = jax.tree.map(lambda x: jax.ShapeDtypeStruct(x.shape[1:], x.dtype),
+                         params["blocks"])
+    specs = param_pspecs(layer, mesh, DATA4)
+    layer = jax.tree.map(
+        lambda x, s: jax.ShapeDtypeStruct(x.shape, x.dtype,
+                                          sharding=NamedSharding(mesh, s)),
+        layer, specs)
+    gather = make_param_gather(mesh, DATA4, CollectiveConfig(fsdp_mode=mode))
+    compiled = jax.jit(gather).lower(layer).compile()
+    assert "collective-permute" in compiled.as_text()
+    outs = jax.tree.leaves(layer)
+    for x, sh in zip(outs, jax.tree.leaves(compiled.output_shardings)):
+        assert sh.shard_shape(x.shape) == x.shape, (x.shape, sh)
+
+
+def test_ring_rdma_kernel_compiles_for_2x2(topo):
+    mesh = make_mesh((4,), ("ring",), devices=topo.devices)
+    x = jax.ShapeDtypeStruct((4 * 8, 128), jnp.float32,
+                             sharding=NamedSharding(mesh, P("ring", None)))
+    f = jax.shard_map(lambda xs: ring_allgather_tpu(xs, n_devices=4), mesh=mesh,
+                      in_specs=P("ring", None), out_specs=P(None, None),
+                      check_vma=False)
+    compiled = jax.jit(f).lower(x).compile()
+    assert "tpu_custom_call" in compiled.as_text()
