@@ -1,0 +1,8 @@
+"""Device: share of the traced window in which no op ran on the device,
+averaged over the devices."""
+
+
+def read(run):
+    if run.device is None or run.device["window_s"] <= 0:
+        return None
+    return 100.0 * (1.0 - run.device["busy_s"] / run.device["window_s"])
