@@ -1,0 +1,12 @@
+"""MLP: device self time of the ops in the program's ``mlp`` scope (norm,
+the MLP or MoE layer and the residual add; forward, remat recompute and
+backward), per window step, averaged over the devices. None where the trace
+names no such scope."""
+from chipbench import scopes
+
+
+def read(run):
+    red = scopes.for_run(run)
+    if red is None or not run.steps or scopes.scope_s(red, "mlp") <= 0:
+        return None
+    return 1e3 * scopes.scope_s(red, "mlp") / run.steps

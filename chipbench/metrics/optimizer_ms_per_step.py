@@ -1,0 +1,11 @@
+"""Optimizer: device self time of the ops in the program's ``optimizer``
+scope (global-norm clipping and the AdamW update), per window step,
+averaged over the devices. None where the trace names no such scope."""
+from chipbench import scopes
+
+
+def read(run):
+    red = scopes.for_run(run)
+    if red is None or not run.steps or scopes.scope_s(red, "optimizer") <= 0:
+        return None
+    return 1e3 * scopes.scope_s(red, "optimizer") / run.steps

@@ -25,4 +25,11 @@ def enable_compile_cache() -> str:
     if not path:
         path = str(DEFAULT_DIR)
         jax.config.update("jax_compilation_cache_dir", path)
+    # JAX leaves the HLO metadata out of the key by default, so a program
+    # that differs only in its named scopes would be served an executable
+    # compiled before them, and a profile of it would name the old scopes.
+    # Source paths are cut to the file name so that checkouts at other paths
+    # share entries.
+    jax.config.update("jax_compilation_cache_include_metadata_in_key", True)
+    jax.config.update("jax_hlo_source_file_canonicalization_regex", r".*/")
     return path

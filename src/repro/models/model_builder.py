@@ -165,8 +165,9 @@ def build_model(cfg: ModelConfig, remat: str = "none") -> ModelApi:
 
     def loss_fn(params, batch):
         x, aux, _ = fwd(params, cfg, batch, remat=remat)
-        x = _final_hidden(params, cfg, x)
-        loss = chunked_xent(x, _head_matrix(params, cfg), batch["targets"])
+        with jax.named_scope("xent"):
+            x = _final_hidden(params, cfg, x)
+            loss = chunked_xent(x, _head_matrix(params, cfg), batch["targets"])
         metrics = {"xent": loss}
         if fam == "moe":
             loss = loss + aux["moe_aux"] + aux["moe_zloss"]

@@ -52,31 +52,37 @@ def _attn_head_spec(cfg: ModelConfig):
 
 
 def dense_block_apply(p, x: jax.Array, cfg: ModelConfig, *, positions, want_kv: bool):
-    """Train/prefill path. x (B,S,D). Returns (x, aux_metrics, (k,v)|None)."""
+    """Train/prefill path. x (B,S,D). Returns (x, aux_metrics, (k,v)|None).
+
+    The two halves run under the named scopes ``attention`` and ``mlp``; JAX
+    carries them into the backward pass and the remat recompute, so a
+    profiler trace can be split by layer (``op_name`` in the compiled HLO)."""
     hspec = _attn_head_spec(cfg)
-    h = layers.rms_norm(x, p["ln1"], cfg.norm_eps)
-    h = shard(h, "dp", None, None)
-    q, k, v = layers.qkv_split(p["attn"], h, cfg)
-    q = apply_positions(q, positions, cfg)
-    k = apply_positions(k, positions, cfg)
-    q = shard(q, "dp", None, hspec, None)
-    o = attn.blockwise_attention(
-        q, k, v,
-        causal=True,
-        window=cfg.attn_window,
-        q_block=cfg.attn_q_block,
-        kv_block=cfg.attn_kv_block,
-        softcap=cfg.attn_logit_softcap,
-    )
-    x = x + shard(layers.out_proj(p["attn"], o), "dp", "sp", None)
-    h2 = layers.rms_norm(x, p["ln2"], cfg.norm_eps)
-    h2 = shard(h2, "dp", None, None)
-    aux = {}
-    if cfg.family == "moe":
-        ff, aux = moe.moe_apply(p["moe"], h2, cfg)
-    else:
-        ff = layers.mlp_apply(p["mlp"], h2, _mlp_act(cfg))
-    x = x + shard(ff, "dp", "sp", None)
+    with jax.named_scope("attention"):
+        h = layers.rms_norm(x, p["ln1"], cfg.norm_eps)
+        h = shard(h, "dp", None, None)
+        q, k, v = layers.qkv_split(p["attn"], h, cfg)
+        q = apply_positions(q, positions, cfg)
+        k = apply_positions(k, positions, cfg)
+        q = shard(q, "dp", None, hspec, None)
+        o = attn.blockwise_attention(
+            q, k, v,
+            causal=True,
+            window=cfg.attn_window,
+            q_block=cfg.attn_q_block,
+            kv_block=cfg.attn_kv_block,
+            softcap=cfg.attn_logit_softcap,
+        )
+        x = x + shard(layers.out_proj(p["attn"], o), "dp", "sp", None)
+    with jax.named_scope("mlp"):
+        h2 = layers.rms_norm(x, p["ln2"], cfg.norm_eps)
+        h2 = shard(h2, "dp", None, None)
+        aux = {}
+        if cfg.family == "moe":
+            ff, aux = moe.moe_apply(p["moe"], h2, cfg)
+        else:
+            ff = layers.mlp_apply(p["mlp"], h2, _mlp_act(cfg))
+        x = x + shard(ff, "dp", "sp", None)
     kv = None
     if want_kv:
         kv = (k.transpose(0, 2, 1, 3), v.transpose(0, 2, 1, 3))  # (B,KV,S,hd)
@@ -171,10 +177,11 @@ def lm_init(rng, cfg: ModelConfig) -> Params:
 
 
 def embed_tokens(params, cfg: ModelConfig, tokens: jax.Array) -> jax.Array:
-    x = jnp.take(params["embed"], tokens, axis=0)
-    if cfg.family == "hybrid":  # gemma-style embedding scale
-        x = x * jnp.asarray(np.sqrt(cfg.d_model), x.dtype)
-    return shard(x, "dp", "sp", None)
+    with jax.named_scope("embed"):
+        x = jnp.take(params["embed"], tokens, axis=0)
+        if cfg.family == "hybrid":  # gemma-style embedding scale
+            x = x * jnp.asarray(np.sqrt(cfg.d_model), x.dtype)
+        return shard(x, "dp", "sp", None)
 
 
 def lm_logits(params, cfg: ModelConfig, x: jax.Array) -> jax.Array:

@@ -51,8 +51,10 @@ def clip_by_global_norm(grads, max_norm: float):
     return jax.tree.map(lambda g: g * scale.astype(g.dtype), grads), gn
 
 
+@jax.named_scope("optimizer")
 def apply_updates(params, grads, opt: OptState, tc: TrainConfig):
-    """One AdamW step. Returns (new_params, new_opt, metrics)."""
+    """One AdamW step, clipping included, under the named scope
+    ``optimizer``. Returns (new_params, new_opt, metrics)."""
     grads, gn = clip_by_global_norm(grads, tc.grad_clip)
     step = opt.step + 1
     lr = lr_schedule(step, tc)

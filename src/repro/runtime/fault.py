@@ -69,32 +69,44 @@ class TrainSupervisor:
     async_ckpt: bool = True
 
     def run(self, state, n_steps: int, start_step: int = 0):
+        """Steps ``start_step`` .. ``n_steps - 1``. Each iteration is a
+        ``train`` step in the profiler's trace, and its host phases are
+        spans on the trace's clock: ``train.next_batch``, ``train.sync``
+        (the step's dispatch and the wait for its result), ``train.record``
+        (metrics to host floats, the straggler monitor) and
+        ``train.checkpoint`` (only when a save starts). With no profiler
+        running they are no-ops."""
         history = []
         step = start_step
         restarts = 0
         pending = None
         while step < n_steps:
             try:
-                batch = self.pipeline.next_batch(step)
-                if self.injector:
-                    self.injector.check(step)
-                t0 = time.monotonic()
-                # block: dt is the step's device time, not its dispatch
-                state, metrics = jax.block_until_ready(self.step_fn(state, batch))
-                dt = time.monotonic() - t0
-                self.monitor.observe(step, dt)
-                history.append({"step": step, "dt": dt, **{
-                    k: float(v) for k, v in metrics.items()
-                }})
-                step += 1
-                if self.ckpt_every and step % self.ckpt_every == 0:
-                    if pending is not None and not self.async_ckpt:
-                        pending = None
-                    pending = save(
-                        state, self.ckpt_dir, step,
-                        blocking=not self.async_ckpt,
-                        metadata={"step": step},
-                    )
+                with jax.profiler.StepTraceAnnotation("train", step_num=step):
+                    with jax.profiler.TraceAnnotation("train.next_batch"):
+                        batch = self.pipeline.next_batch(step)
+                    if self.injector:
+                        self.injector.check(step)
+                    t0 = time.monotonic()
+                    # block: dt is the step's device time, not its dispatch
+                    with jax.profiler.TraceAnnotation("train.sync"):
+                        state, metrics = jax.block_until_ready(self.step_fn(state, batch))
+                    dt = time.monotonic() - t0
+                    with jax.profiler.TraceAnnotation("train.record"):
+                        self.monitor.observe(step, dt)
+                        history.append({"step": step, "dt": dt, **{
+                            k: float(v) for k, v in metrics.items()
+                        }})
+                    step += 1
+                    if self.ckpt_every and step % self.ckpt_every == 0:
+                        if pending is not None and not self.async_ckpt:
+                            pending = None
+                        with jax.profiler.TraceAnnotation("train.checkpoint"):
+                            pending = save(
+                                state, self.ckpt_dir, step,
+                                blocking=not self.async_ckpt,
+                                metadata={"step": step},
+                            )
             except InjectedFailure:
                 restarts += 1
                 if restarts > self.max_restarts:

@@ -68,11 +68,13 @@ def _resolve(dim) -> object:
 
 def maybe_gather_params(tree):
     """Hook called inside layer-scan bodies: explicit FSDP gather (paper
-    schedule) when active, identity otherwise (XLA auto-gather)."""
+    schedule, under the named scope ``fsdp_gather``) when active, identity
+    otherwise (XLA auto-gather)."""
     c = get_ctx()
     if c.gather_params is None:
         return tree
-    return c.gather_params(tree)
+    with jax.named_scope("fsdp_gather"):
+        return c.gather_params(tree)
 
 
 def shard(x: jax.Array, *dims) -> jax.Array:
