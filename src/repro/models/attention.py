@@ -1,11 +1,18 @@
-"""Attention: chunked online-softmax (flash-style) for train/prefill, and
-sequence-parallel flash-decoding for the serve path.
+"""Attention: causal attention for train/prefill, as one fused Pallas kernel
+on the TPU or as chunked online-softmax scans, and sequence-parallel
+flash-decoding for the serve path.
 
-Memory: the chunked path never materializes (S x S) scores — it scans over KV
-blocks carrying the online-softmax state (m, l, acc), so the working set is
-O(S * q_block) per step. Causality/windowing is applied as a block mask; fully
-masked-out KV blocks still cost FLOPs in the baseline (recorded as a §Perf
-hillclimb opportunity in EXPERIMENTS.md).
+Train/prefill: ``causal_attention`` is what the dense block calls. On a TPU,
+where the shapes, mask and mesh fit (``fused_attention_blocks``), it runs
+``fused_causal_attention``: the Splash kernel of
+``jax.experimental.pallas.ops.tpu.splash_attention``, which keeps each score
+tile in VMEM, saves only the per-row log-sum-exp for its backward, recomputes
+the scores there tile by tile, and skips the tiles wholly above the
+diagonal. Everywhere else it runs ``blockwise_attention``: a scan over Q
+blocks around an online-softmax scan over KV blocks carrying (m, l, acc), so
+the working set is O(q_block * kv_block) scores; it takes windows, soft caps
+and non-causal masks, computes every block pair (fully masked ones too), and
+autodiff keeps each block's probabilities for the backward.
 
 Decode: KV caches are laid out (B, KV, S, hd) with the sequence dim sharded
 over the ``model`` mesh axis. ``flash_decode`` computes per-shard partial
@@ -14,11 +21,15 @@ flash-decoding), so a 32k-context cache never needs gathering.
 """
 from __future__ import annotations
 
+import functools
 from typing import Optional
 
 import jax
 import jax.numpy as jnp
+from jax.experimental.pallas.ops.tpu import splash_attention as splash
 from jax.sharding import PartitionSpec as P
+
+from repro.sharding import get_ctx, mesh_axis_size
 
 NEG_INF = -1e30
 
@@ -200,6 +211,134 @@ def _attend_block(
     (m, l, acc), _ = jax.lax.scan(inner, (m0, l0, a0), (kb, vb, jnp.arange(nkb)))
     out = acc / jnp.maximum(l, 1e-30)[..., None]
     return out.transpose(0, 3, 1, 2, 4).reshape(b, sq, h, hd).astype(qblk.dtype)
+
+
+# ------------------------------------------------------------ fused (TPU)
+
+# the kernel's tiles are whole multiples of the vector lanes
+_LANES = 128
+
+
+def fused_attention_blocks(
+    q_shape: tuple[int, ...],  # (B, S, H, hd)
+    k_shape: tuple[int, ...],  # (B, S, KV, hd)
+    *,
+    window: Optional[int],
+    softcap: Optional[float],
+    q_block: int,
+    kv_block: int,
+    batch_shards: int,
+    head_shards: int,
+) -> Optional[tuple[int, int]]:
+    """The (q, kv) tile sizes of ``fused_causal_attention`` where, on a TPU,
+    it computes what causal ``blockwise_attention`` computes, else None.
+
+    It does for plain causal self-attention (no window or soft cap) where
+    the blocks clipped to S are multiples of 128 that divide S, the head
+    size is one the kernel tiles (at most 128, or a multiple of 128), and
+    the mesh splits the batch and the query and KV heads evenly
+    (``*_shards`` are the mesh sizes that split each dimension; 1 without a
+    mesh).
+    """
+    b, s, h, hd = q_shape
+    kvh = k_shape[2]
+    bq, bkv = min(q_block, s), min(kv_block, s)
+    fits = (
+        window is None and softcap is None
+        and bq % _LANES == 0 and bkv % _LANES == 0 and s % bq == 0 and s % bkv == 0
+        and (hd <= 128 or hd % 128 == 0)
+        and b % batch_shards == 0 and h % kvh == 0
+        and h % head_shards == 0 and kvh % head_shards == 0
+    )
+    return (bq, bkv) if fits else None
+
+
+@functools.lru_cache(maxsize=None)
+def _splash_kernel(heads: int, seq: int, q_block: int, kv_block: int, interpret: bool):
+    """One causal Splash kernel per shape, built once: its block masks are
+    concrete arrays, made outside any trace so later traces can share them."""
+    mask = splash.MultiHeadMask([splash.CausalMask((seq, seq))] * heads)
+    blocks = splash.BlockSizes(
+        block_q=q_block, block_kv=kv_block, block_kv_compute=kv_block,
+        block_q_dkv=q_block, block_kv_dkv=kv_block, block_kv_dkv_compute=kv_block,
+        block_q_dq=q_block, block_kv_dq=kv_block,
+    )
+    with jax.ensure_compile_time_eval():
+        return splash.make_splash_mha(mask, block_sizes=blocks, head_shards=1,
+                                      q_seq_shards=1, interpret=interpret)
+
+
+def fused_causal_attention(
+    q: jax.Array,  # (B, S, H, hd)
+    k: jax.Array,  # (B, S, KV, hd)
+    v: jax.Array,  # (B, S, KV, hd)
+    *,
+    q_block: int,
+    kv_block: int,
+    interpret: bool = False,
+) -> jax.Array:
+    """Causal softmax attention through the Splash kernel, vmapped over the
+    batch; its softmax statistics and accumulators are float32, its
+    matmuls take q, k and v in their own dtype, and the result has q's.
+
+    The kernel takes no scale, so hd ** -0.5 is folded into q first, in
+    q's dtype: exact where hd is a power of four; elsewhere (yi-9b's 128)
+    the scale and the scaled q are rounded to q's dtype, roundings the
+    scans, which scale float32 scores, do not make. Under an active mesh
+    it runs inside a ``shard_map`` over the batch axes and, where ``tp``
+    splits the heads, the heads: a Pallas call has no partitioning rule of
+    its own.
+    """
+    def attend(q, k, v):
+        _, s, h, hd = q.shape
+        kernel = _splash_kernel(h, s, q_block, kv_block, interpret)
+        qs = (q * jnp.asarray(hd ** -0.5, q.dtype)).transpose(0, 2, 1, 3)
+        o = jax.vmap(kernel)(qs, k.transpose(0, 2, 1, 3), v.transpose(0, 2, 1, 3))
+        return o.transpose(0, 2, 1, 3)
+
+    ctx = get_ctx()
+    if ctx.mesh is None:
+        return attend(q, k, v)
+    heads = ctx.tp_axis if mesh_axis_size("tp") > 1 else None
+    spec = P(ctx.dp_axes if ctx.shard_batch else None, None, heads, None)
+    return jax.shard_map(attend, mesh=ctx.mesh, in_specs=(spec, spec, spec),
+                         out_specs=spec, check_vma=False)(q, k, v)
+
+
+def causal_attention(
+    q: jax.Array,  # (B, S, H, hd)
+    k: jax.Array,  # (B, S, KV, hd)
+    v: jax.Array,
+    *,
+    window: Optional[int] = None,
+    q_block: int = 512,
+    kv_block: int = 1024,
+    softcap: Optional[float] = None,
+) -> jax.Array:
+    """Causal self-attention of the train/prefill path: the fused kernel
+    where ``fused_attention_blocks`` admits it for a TPU, and
+    ``blockwise_attention`` everywhere else.
+
+    The platform is the one the step is lowered for (``platform_dependent``),
+    so a compile for a described TPU on another host takes the kernel too;
+    only that platform's branch is lowered. q, k and v hold the whole
+    sequence: the dense block gathers the residual stream's ``sp`` split
+    before its QKV projection.
+    """
+    blockwise = functools.partial(
+        blockwise_attention, causal=True, window=window, q_block=q_block,
+        kv_block=kv_block, softcap=softcap,
+    )
+    ctx = get_ctx()
+    blocks = fused_attention_blocks(
+        q.shape, k.shape, window=window, softcap=softcap, q_block=q_block,
+        kv_block=kv_block, batch_shards=mesh_axis_size("dp") if ctx.shard_batch else 1,
+        head_shards=mesh_axis_size("tp"),
+    )
+    if blocks is None:
+        return blockwise(q, k, v)
+    fused = functools.partial(fused_causal_attention, q_block=blocks[0], kv_block=blocks[1])
+    return jax.lax.platform_dependent(q, k, v, tpu=fused, default=blockwise)
 
 
 # --------------------------------------------------------------------- decode

@@ -65,9 +65,8 @@ def dense_block_apply(p, x: jax.Array, cfg: ModelConfig, *, positions, want_kv: 
         q = apply_positions(q, positions, cfg)
         k = apply_positions(k, positions, cfg)
         q = shard(q, "dp", None, hspec, None)
-        o = attn.blockwise_attention(
+        o = attn.causal_attention(
             q, k, v,
-            causal=True,
             window=cfg.attn_window,
             q_block=cfg.attn_q_block,
             kv_block=cfg.attn_kv_block,

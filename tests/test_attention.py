@@ -1,4 +1,7 @@
-"""Blockwise online-softmax attention vs a naive oracle (+ decode paths)."""
+"""Blockwise online-softmax attention and the fused kernel vs a naive oracle
+(+ the dispatch between them, and decode paths)."""
+import functools
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -108,3 +111,134 @@ def test_cache_scatter_update():
     for i, p in enumerate([0, 5, 15]):
         assert float(out[i, :, p].sum()) == kv * hd
     assert float(out.sum()) == b * kv * hd
+
+
+# ------------------------------------------------ fused kernel (interpret mode)
+
+
+def _qkv(b, s, h, kv, hd, seed=0, dtype=jnp.bfloat16):
+    rng = np.random.default_rng(seed)
+    return tuple(jnp.asarray(rng.standard_normal(shape), dtype)
+                 for shape in ((b, s, h, hd), (b, s, kv, hd), (b, s, kv, hd)))
+
+
+def _grads(f, q, k, v):
+    # a fixed cotangent that differs across the head dim
+    w = jnp.cos(jnp.arange(q.shape[-1], dtype=jnp.float32))
+    return jax.grad(lambda q, k, v: jnp.sum(f(q, k, v).astype(jnp.float32) * w),
+                    argnums=(0, 1, 2))(q, k, v)
+
+
+def _rel(a, b):
+    a, b = np.asarray(a, np.float32), np.asarray(b, np.float32)
+    return float(np.linalg.norm(a - b) / np.linalg.norm(b))
+
+
+@pytest.mark.parametrize("h,kv", [(9, 3), (4, 4)], ids=["gqa9-3", "mha4"])
+@pytest.mark.parametrize("hd", [64, 128])
+@pytest.mark.parametrize("s", [256, 512])
+def test_fused_matches_blockwise_and_naive(h, kv, hd, s):
+    """The Splash kernel (Pallas interpret mode) against the scans and a
+    float32 oracle, bf16 inputs: the output and the q, k and v gradients."""
+    q, k, v = _qkv(1, s, h, kv, hd)
+    fused = jax.jit(lambda q, k, v: A.fused_causal_attention(
+        q, k, v, q_block=128, kv_block=256, interpret=True))
+    blockwise = jax.jit(lambda q, k, v: A.blockwise_attention(
+        q, k, v, causal=True, q_block=128, kv_block=256))
+    f32 = [x.astype(jnp.float32) for x in (q, k, v)]
+    naive = naive_attention(*f32)
+
+    out = fused(q, k, v)
+    assert out.dtype == q.dtype and out.shape == q.shape
+    np.testing.assert_allclose(np.asarray(out, np.float32), np.asarray(naive), atol=1.6e-2)
+    np.testing.assert_allclose(np.asarray(out, np.float32),
+                               np.asarray(blockwise(q, k, v), np.float32), atol=1.6e-2)
+
+    g_fused = _grads(fused, q, k, v)
+    g_block = _grads(blockwise, q, k, v)
+    g_naive = _grads(naive_attention, *f32)
+    for name, gf, gb, gn in zip("qkv", g_fused, g_block, g_naive):
+        assert gf.dtype == jnp.bfloat16, name
+        # bf16 gradients: the kernel's gap to the oracle is the scans' own
+        assert _rel(gf, gn) < 1e-2, (name, _rel(gf, gn), _rel(gb, gn))
+        assert _rel(gf, gb) < 1e-2, (name, _rel(gf, gb))
+
+
+CELL = dict(q_shape=(16, 2048, 9, 64), k_shape=(16, 2048, 3, 64), window=None,
+            softcap=None, q_block=512, kv_block=1024, batch_shards=1, head_shards=1)
+
+
+@pytest.mark.parametrize("change,blocks", [
+    ({}, (512, 1024)),                                    # the benchmark cell's shapes
+    ({"window": 1024}, None),
+    ({"softcap": 50.0}, None),
+    ({"window": 1024, "softcap": 50.0}, None),
+    ({"q_shape": (16, 2000, 9, 64), "k_shape": (16, 2000, 3, 64)}, None),  # S % blocks
+    ({"q_shape": (16, 1536, 9, 64), "k_shape": (16, 1536, 3, 64)}, None),  # S % kv_block
+    ({"q_shape": (16, 512, 9, 64), "k_shape": (16, 512, 3, 64)}, (512, 512)),  # clipped
+    ({"q_shape": (2, 64, 9, 64), "k_shape": (2, 64, 3, 64)}, None),  # tiles under 128
+    ({"q_block": 32, "kv_block": 32}, None),              # the CPU-sized configs' blocks
+    ({"q_shape": (16, 2048, 9, 96), "k_shape": (16, 2048, 3, 96)}, (512, 1024)),
+    ({"q_shape": (16, 2048, 9, 192), "k_shape": (16, 2048, 3, 192)}, None),  # hd
+    ({"q_shape": (16, 2048, 8, 256), "k_shape": (16, 2048, 8, 256)}, (512, 1024)),
+    # yi-9b's heads under FSDP over data=4
+    ({"q_shape": (8, 2048, 32, 128), "k_shape": (8, 2048, 4, 128), "batch_shards": 4},
+     (512, 1024)),
+    ({"batch_shards": 4}, (512, 1024)),
+    ({"batch_shards": 3}, None),
+    ({"head_shards": 3}, (512, 1024)),                    # 9 and 3 heads over tp=3
+    ({"head_shards": 2}, None),                           # 9 heads over tp=2
+    ({"q_shape": (16, 2048, 8, 64), "k_shape": (16, 2048, 1, 64), "head_shards": 2}, None),
+])
+def test_fused_attention_dispatch_rule(change, blocks):
+    args = {**CELL, **change}
+    q_shape, k_shape = args.pop("q_shape"), args.pop("k_shape")
+    assert A.fused_attention_blocks(q_shape, k_shape, **args) == blocks
+
+
+def test_causal_attention_takes_the_scans_when_lowered_for_the_cpu():
+    """Shapes the kernel takes on a TPU: lowered for the CPU, the dispatcher
+    holds no kernel and its output and gradients are the scans' exactly."""
+    q, k, v = _qkv(2, 256, 9, 3, 64)
+    f = functools.partial(A.causal_attention, q_block=128, kv_block=128)
+    assert A.fused_attention_blocks(q.shape, k.shape, window=None, softcap=None,
+                                    q_block=128, kv_block=128, batch_shards=1, head_shards=1)
+    text = jax.jit(f).lower(q, k, v).as_text()
+    assert "tpu_custom_call" not in text and "splash" not in text
+    ref = functools.partial(A.blockwise_attention, causal=True, q_block=128, kv_block=128)
+    np.testing.assert_array_equal(np.asarray(jax.jit(f)(q, k, v), np.float32),
+                                  np.asarray(ref(q, k, v), np.float32))
+    for a, b in zip(_grads(f, q, k, v), _grads(ref, q, k, v)):
+        np.testing.assert_array_equal(np.asarray(a, np.float32), np.asarray(b, np.float32))
+
+
+def test_fused_under_a_mesh_equals_no_mesh(multidev):
+    """Four CPU devices: the kernel inside ``shard_map`` over data=4, and
+    over data=2 x tp=2 with the heads split, gives the unsharded result."""
+    out = multidev(
+        """
+import jax, jax.numpy as jnp, numpy as np
+from repro.launch.mesh import make_mesh
+from repro.models import attention as A
+from repro.sharding import ShardCtx, use_ctx
+
+rng = np.random.default_rng(0)
+q, k, v = (jnp.asarray(rng.standard_normal(s), jnp.bfloat16)
+           for s in ((4, 256, 4, 64), (4, 256, 2, 64), (4, 256, 2, 64)))
+
+def run(ctx):
+    with use_ctx(ctx):
+        f = lambda q, k, v: A.fused_causal_attention(q, k, v, q_block=128, kv_block=128,
+                                                     interpret=True)
+        loss = lambda q, k, v: jnp.sum(f(q, k, v).astype(jnp.float32) ** 2)
+        return jax.jit(lambda q, k, v: (f(q, k, v), jax.grad(loss, (0, 1, 2))(q, k, v)))(q, k, v)
+
+base = run(ShardCtx(mesh=None))
+for shape in ((4, 1), (2, 2)):
+    mesh = make_mesh(shape, ("data", "model"))
+    got = run(ShardCtx(mesh=mesh, dp_axes=("data",), tp_axis="model"))
+    same = all(np.array_equal(np.asarray(a, np.float32), np.asarray(b, np.float32))
+               for a, b in zip(jax.tree.leaves(got), jax.tree.leaves(base)))
+    print("mesh", shape, "equal" if same else "differ")
+""", n_devices=4)
+    assert "mesh (4, 1) equal" in out and "mesh (2, 2) equal" in out, out

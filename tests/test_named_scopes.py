@@ -2,6 +2,7 @@
 named scopes reach the compiled HLO's ``op_name`` metadata (forward,
 backward and the FSDP gather), and ``TrainSupervisor.run`` writes its
 ``train.*`` spans into a profiler trace without changing what it computes."""
+import dataclasses
 import glob
 import os
 import re
@@ -58,6 +59,32 @@ def test_layer_scopes_cover_the_remat_recompute(op_names):
     recompute = [n for n in op_names if "rematted_computation" in n]
     for scope in ("attention", "mlp"):
         assert _scope_paths(recompute, scope), scope
+
+
+def test_attention_kernel_called_in_the_attention_scope_when_lowered_for_tpu():
+    """Lowered for a TPU (a lowering needs no chip), the step runs attention
+    as the fused kernel: its forward, dq and dkv custom calls are there, and
+    every call of the kernel is made inside the ``attention`` scope, in the
+    remat recompute too. Lowered for the CPU, the same step holds none.
+    The compiled ``op_name``s are checked for a described v5e in
+    ``test_v5e_compile.py``."""
+    run = _run()
+    run = run.replace(model=dataclasses.replace(run.model, attn_q_block=128, attn_kv_block=128),
+                      shape=ShapeConfig("t", "train", 256, 2))
+    _, _, step = make_train_step(run, None)
+    batch = {k: jax.ShapeDtypeStruct(v, jnp.int32)
+             for k, v in batch_dims(run.model, run.shape).items()}
+    traced = jax.jit(step).trace(abstract_state(run), batch)
+    text = traced.lower(lowering_platforms=("tpu",)).as_text(debug_info=True)
+    locs = dict(re.findall(r"^(#loc\d+) = loc\(\"([^\"]*)\"", text, re.M))
+    kernels = {locs[n] for n in re.findall(r"@tpu_custom_call.*loc\((#loc\d+)\)$", text, re.M)}
+    assert {re.match(r"splash_mha_(fwd|dq|dkv)_", k).group(1) for k in kernels} == \
+        {"fwd", "dq", "dkv"}, kernels
+    calls = [locs[n] for n in re.findall(r"call @_splash_attention\w*\(.*loc\((#loc\d+)\)$",
+                                         text, re.M)]
+    assert calls and all(_scope_paths([c], "attention") for c in calls), calls
+    assert any("rematted_computation" in c for c in calls), calls
+    assert "tpu_custom_call" not in traced.lower(lowering_platforms=("cpu",)).as_text()
 
 
 def test_fsdp_gather_scope_on_the_mcast_permutes(multidev):

@@ -9,6 +9,7 @@ compile cache is off around them: entries compiled for a described chip
 cannot be read back without one.
 """
 import dataclasses
+import re
 
 import jax
 import jax.numpy as jnp
@@ -70,6 +71,32 @@ def test_smollm_train_step_fits_one_chip(topo):
     used = (mem.argument_size_in_bytes + mem.output_size_in_bytes
             + mem.temp_size_in_bytes - mem.alias_size_in_bytes)
     assert 0 < used < V5E_HBM_BYTES, mem
+
+    # attention runs as the fused kernel: its forward, remat recompute and
+    # backward (dq, dkv) custom calls, all inside the ``attention`` scope
+    text = compiled.as_text()
+    kernels = {}
+    for name, body in _instructions(text):
+        if 'custom_call_target="tpu_custom_call"' in body:
+            kernels[name] = re.search(r'op_name="([^"]*)"', body).group(1)
+    assert kernels, "no kernel in the compiled step"
+    kinds = {re.match(r"splash_mha_(fwd|dq|dkv)_", n).group(1) for n in kernels}
+    assert kinds == {"fwd", "dq", "dkv"}, sorted(kernels)
+    for name, op_name in kernels.items():
+        assert re.search(r"(^|/)(\w+\()*attention\)*/", op_name), (name, op_name)
+    passes = {("backward" if "transpose(" in op else "forward") +
+              ("+recompute" if "rematted_computation" in op else "")
+              for op in kernels.values()}
+    assert passes == {"forward", "backward+recompute", "backward"}, passes
+    # and the scans' float32 (q_block, kv_block) score blocks are gone
+    assert not re.search(r"f32\[[\d,]*512,1024\]", text)
+
+
+def _instructions(text):
+    """(name, text) of each instruction of compiled HLO text; an
+    instruction's attributes may run over several lines."""
+    return re.findall(r"^\s+(?:ROOT )?%([\w.\-]+) = (.*?)"
+                      r"(?=^\s+(?:ROOT )?%[\w.\-]+ = |^\}$|\Z)", text, re.M | re.S)
 
 
 @pytest.mark.parametrize("mode", ["mcast", "mcast_bcast"])
