@@ -9,7 +9,9 @@ compile cache is off around them: entries compiled for a described chip
 cannot be read back without one.
 """
 import dataclasses
+import os
 import re
+import sys
 
 import jax
 import jax.numpy as jnp
@@ -27,6 +29,7 @@ from repro.sharding.fsdp import make_param_gather
 from repro.sharding.specs import param_pspecs
 
 V5E_HBM_BYTES = 16 * 2**30
+ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 DATA4 = MeshConfig((4, 1), ("data", "model"))
 
 
@@ -120,6 +123,42 @@ def test_yi9b_layer_gather_on_data4(topo, mode):
     outs = jax.tree.leaves(layer)
     for x, sh in zip(outs, jax.tree.leaves(compiled.output_shardings)):
         assert sh.shard_shape(x.shape) == x.shape, (x.shape, sh)
+
+
+def test_yi9b_l8_mcast_step_fits_2x2(topo):
+    """The four-chip benchmark cell's whole train step (yi-9b at published
+    widths, 8 layers, B=8 x S=2048, ``mcast`` gathers over data=4, full
+    remat), as the benchmark assembles it, fits each chip; its ring sends
+    ZeRO-3's bytes less the replicated norms' share, and attention is the
+    fused kernel inside ``shard_map``."""
+    sys.path.insert(0, ROOT)
+    from chipbench import cell as C
+    from chipbench import zero3
+    from chipbench.harness import Program
+    from repro.launch.hlo_stats import _loop_multiplier, _shape_bytes
+
+    cell = C.load("yi9b-l8-fsdp4-mcast")
+    prog = Program(cell, topo.devices)
+    run = prog.run_cfg
+    batch = {k: jax.ShapeDtypeStruct(v, jnp.int32)
+             for k, v in batch_dims(run.model, run.shape).items()}
+    compiled = prog.step_fn.lower(abstract_state(run), batch).compile()
+    mem = compiled.memory_analysis()
+    used = (mem.argument_size_in_bytes + mem.output_size_in_bytes
+            + mem.temp_size_in_bytes - mem.alias_size_in_bytes)
+    assert 0 < used < V5E_HBM_BYTES, mem
+
+    # each collective-permute-done holds what its start received; it runs
+    # once per layer and ring step (the layer scan, and the ring's P - 1)
+    text = compiled.as_text()
+    sent = 0
+    for name, body in _instructions(text):
+        m = re.match(r"(\S+) collective-permute-done\(", body)
+        if m:
+            sent += _shape_bytes(m.group(1)) * _loop_multiplier(body, (8, 3))
+    norms = 3 * 3 * (2 * 8 * 4096 * 4) // 4
+    assert sent == zero3.bytes_per_step(cell.config) - norms == 6_228_541_440
+    assert "tpu_custom_call" in text
 
 
 def test_ring_rdma_kernel_compiles_for_2x2(topo):
