@@ -28,8 +28,10 @@ _DTYPE_BYTES = {
 }
 
 _SHAPE_RE = re.compile(r"(\w+)\[([\d,]*)\]")
+# the result is the text between "=" and the op: a TPU layout such as
+# {1,0:T(8,128)(2,1)} holds parentheses, so it is not matched as a tuple
 _COLL_RE = re.compile(
-    r"=\s*((?:\([^)]*\))|(?:\w+\[[\d,]*\]\S*))\s+"
+    r"=\s*(.+?)\s+"
     r"(all-gather|all-reduce|reduce-scatter|all-to-all|collective-permute)"
     r"(-start)?\("
 )
@@ -48,6 +50,14 @@ def _shape_bytes(text: str) -> int:
                 n *= int(d)
         total += n * _DTYPE_BYTES[dt]
     return total
+
+
+def _start_result_bytes(text: str) -> int:
+    """Bytes of what an all-gather- or collective-permute-start delivers:
+    its result tuple holds its operands, then its results, then scalar
+    u32[] contexts; the second half of the array shapes are the results."""
+    shapes = [m.group(0) for m in _SHAPE_RE.finditer(text) if m.group(2)]
+    return _shape_bytes(" ".join(shapes[len(shapes) // 2:]))
 
 
 def _group_size(line: str, default: int) -> int:
@@ -104,9 +114,10 @@ def collective_stats(hlo_text: str, n_devices: int,
         if not m:
             continue
         result_txt, op, started = m.group(1), m.group(2), m.group(3)
-        if started and "-done" in line:
-            continue
-        rbytes = _shape_bytes(result_txt)
+        if started and op in ("all-gather", "collective-permute"):
+            rbytes = _start_result_bytes(result_txt)
+        else:
+            rbytes = _shape_bytes(result_txt)
         g = max(_group_size(line, n_devices), 1)
         if op == "all-gather":
             per_dev = rbytes * (g - 1) // max(g, 1)

@@ -301,8 +301,10 @@ def fused_causal_attention(
         return attend(q, k, v)
     heads = ctx.tp_axis if mesh_axis_size("tp") > 1 else None
     spec = P(ctx.dp_axes if ctx.shard_batch else None, None, heads, None)
-    return jax.shard_map(attend, mesh=ctx.mesh, in_specs=(spec, spec, spec),
-                         out_specs=spec, check_vma=False)(q, k, v)
+    # manual over the axes not manual yet: inside a dp-manual region, tp alone
+    return jax.shard_map(attend, mesh=ctx.mesh, in_specs=(spec, spec, spec), out_specs=spec,
+                         axis_names=set(ctx.mesh.axis_names) - set(ctx.mesh.manual_axes),
+                         check_vma=False)(q, k, v)
 
 
 def causal_attention(
@@ -315,15 +317,13 @@ def causal_attention(
     kv_block: int = 1024,
     softcap: Optional[float] = None,
 ) -> jax.Array:
-    """Causal self-attention of the train/prefill path: the fused kernel
-    where ``fused_attention_blocks`` admits it for a TPU, and
-    ``blockwise_attention`` everywhere else.
-
-    The platform is the one the step is lowered for (``platform_dependent``),
-    so a compile for a described TPU on another host takes the kernel too;
-    only that platform's branch is lowered. q, k and v hold the whole
-    sequence: the dense block gathers the residual stream's ``sp`` split
-    before its QKV projection.
+    """Causal self-attention of the train/prefill path: the fused kernel where
+    ``fused_attention_blocks`` admits it for a TPU, ``blockwise_attention``
+    everywhere else. The platform is the one the step is lowered for
+    (``platform_dependent``), so a compile for a described TPU on another
+    host takes the kernel too; only that platform's branch is lowered. q, k
+    and v hold the whole sequence: the dense block gathers the residual
+    stream's ``sp`` split before its QKV projection.
     """
     blockwise = functools.partial(
         blockwise_attention, causal=True, window=window, q_block=q_block,

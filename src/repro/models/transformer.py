@@ -16,7 +16,7 @@ import numpy as np
 from repro.configs.base import ModelConfig
 from repro.models import attention as attn
 from repro.models import layers, moe, rglru, rwkv
-from repro.sharding import get_ctx, shard
+from repro.sharding import get_ctx, shard, use_ctx
 from repro.sharding.ctx import maybe_gather_params
 
 Params = Any
@@ -195,9 +195,9 @@ def lm_logits(params, cfg: ModelConfig, x: jax.Array) -> jax.Array:
 
 def _scan_blocks(params, cfg, x, positions, *, want_kv, remat: str = "none"):
     ctx = get_ctx()
-    if (getattr(ctx, "prefetch_params", False) and ctx.gather_params is not None
-            and not want_kv and cfg.num_layers > 1):
-        return _scan_blocks_prefetch(params, cfg, x, positions, remat=remat)
+    gathered = _gathered_scan(ctx, cfg, want_kv)  # FSDP gathers by the paper's schedules
+    if gathered is not None:
+        return gathered(params, cfg, x, positions, remat=remat)
 
     def body(carry, bp):
         h, aux_acc = carry
@@ -555,3 +555,70 @@ def hybrid_init_cache(cfg: ModelConfig, batch: int, seq_len: int, dtype):
         "attn_v": jnp.zeros((ng, batch, cfg.num_kv_heads, w, cfg.head_dim), dtype),
         "tail": rec_state(max(nt, 1)),
     }
+
+
+# ------------------------------------------------- gathered layers, dp-manual
+
+
+def _gathered_scan(ctx, cfg: ModelConfig, want_kv: bool):
+    """The layer scan that gathers each layer by the paper's schedules, or
+    None for ``_scan_blocks``' own (which gathers through
+    ``maybe_gather_params`` where a gather is set).
+
+    Prefetch keeps its scan. The train path of dense blocks with the batch
+    split over dp runs each layer in a dp-manual region
+    (``_scan_blocks_manual``). Prefill (``want_kv``), MoE blocks (their aux
+    losses and expert axes) and a batch left whole keep the plain scan."""
+    if ctx.gather_params is None or want_kv:
+        return None
+    if ctx.prefetch_params and cfg.num_layers > 1:
+        return _scan_blocks_prefetch
+    if cfg.family != "moe" and ctx.shard_batch:
+        return _scan_blocks_manual
+    return None
+
+
+def _scan_blocks_manual(params, cfg, x, positions, *, remat: str = "none"):
+    """Each layer's gather and ``dense_block_apply`` as one ``shard_map``
+    manual over the dp axes (tp stays automatic). The gathered weights are
+    per-device values, so each weight's cotangent stays a per-device partial
+    from its matmul to the ring reduce-scatter that transposes the gather:
+    the ring is the only reduction of a weight gradient. Replicated leaves
+    (the norms) come out of the transpose as small psums. Model code inside
+    sees the region's mesh and no dp axes, so ``shard`` and the attention
+    kernel's own ``shard_map`` work on the local batch."""
+    # imported here: a line added above would move the source lines that the
+    # HLO metadata, and so the compile cache's key, records for every step
+    import dataclasses
+
+    P = jax.sharding.PartitionSpec
+    ctx = get_ctx()
+    gather = ctx.gather_params
+    specs = gather.specs(jax.tree.map(
+        lambda l: jax.ShapeDtypeStruct(l.shape[1:], l.dtype), params["blocks"]))
+    rows = P(ctx.dp_axes)
+
+    def layer(bp, h, positions):
+        local = dataclasses.replace(ctx, mesh=jax.sharding.get_abstract_mesh(),
+                                    dp_axes=(), shard_batch=False, gather_params=None)
+        with use_ctx(local):
+            with jax.named_scope("fsdp_gather"):
+                bp = gather.local(bp, specs)
+            h, _, _ = dense_block_apply(bp, h, cfg, positions=positions, want_kv=False)
+        return h
+
+    region = jax.shard_map(layer, mesh=ctx.mesh, in_specs=(gather.dp_specs(specs), rows, P()),
+                           out_specs=rows, axis_names=set(ctx.dp_axes), check_vma=False)
+
+    def body(h, bp):
+        return region(bp, h, positions), None
+
+    fn = body
+    if remat == "full":
+        fn = jax.checkpoint(body, prevent_cse=False)
+    elif remat == "dots":
+        fn = jax.checkpoint(
+            body, policy=jax.checkpoint_policies.checkpoint_dots, prevent_cse=False
+        )
+    x, _ = jax.lax.scan(fn, x, params["blocks"])
+    return x, {}, None

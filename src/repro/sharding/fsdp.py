@@ -18,25 +18,18 @@ the axis where the paper's multicast protocol literally applies (DESIGN.md §2).
 """
 from __future__ import annotations
 
-from typing import Callable
+from dataclasses import dataclass
+from functools import partial
 
 import jax
 import jax.numpy as jnp
+from jax import lax
 from jax.sharding import Mesh
 from jax.sharding import PartitionSpec as P
 
 from repro.configs.base import CollectiveConfig, MeshConfig
 from repro.core import collectives as C
 from repro.sharding.specs import _leaf_spec, dp_axes
-
-
-def _remove_axis(entry, axis):
-    if entry is None:
-        return None
-    if isinstance(entry, str):
-        return None if entry == axis else entry
-    rest = tuple(a for a in entry if a != axis)
-    return rest if len(rest) > 1 else (rest[0] if rest else None)
 
 
 def _ag_local(x, axis, mode, n_chains):
@@ -50,69 +43,89 @@ def _ag_local(x, axis, mode, n_chains):
     return C.ring_allgather_local(x, axis)
 
 
-def gather_dim(x: jax.Array, spec: P, axis: str, dim: int, mesh: Mesh,
-               mode: str, n_chains: int) -> tuple[jax.Array, P]:
-    """Explicitly allgather mesh axis ``axis`` out of dim ``dim`` of ``x``."""
-    out_entries = list(spec) + [None] * (x.ndim - len(spec))
-    out_entries[dim] = _remove_axis(out_entries[dim], axis)
-    out_spec = P(*out_entries)
-    p = mesh.shape[axis]
-
-    def local(xl):
-        full = _ag_local(jnp.moveaxis(xl, dim, 0), axis, mode, min(n_chains, p))
-        return jnp.moveaxis(full, 0, dim)
-
-    y = jax.shard_map(
-        local, mesh=mesh, in_specs=spec, out_specs=out_spec, check_vma=False
-    )(x)
-    return y, out_spec
-
-
-def gather_leaf(x: jax.Array, spec: P, mesh: Mesh, dp: tuple[str, ...],
-                mode: str, n_chains: int) -> jax.Array:
-    """Gather every dp-axis out of a weight slice; tp axes stay sharded.
-    Hierarchical: minor (intra-pod "data") ring first, then the "pod" axis
-    via the M-chain broadcast composition."""
-    entries = list(spec)
-    for dim, entry in enumerate(entries):
-        if entry is None:
-            continue
+def gather_leaf_local(x: jax.Array, spec: P, dp: tuple[str, ...], mode: str,
+                      n_chains: int) -> jax.Array:
+    """Gather every dp axis out of ``x``, the local shard of a weight that
+    ``spec`` lays out, inside a region manual over the dp axes; tp axes stay
+    sharded. Hierarchical: minor (intra-pod "data") ring first, then the
+    "pod" axis via the M-chain broadcast composition. Its AD transpose is
+    the matching ring reduce-scatter of the local cotangent."""
+    for dim, entry in enumerate(spec):
         axes = entry if isinstance(entry, tuple) else (entry,)
         for a in [ax for ax in reversed(dp) if ax in axes]:
-            pod_axis = a == "pod"
-            x, spec = gather_dim(
-                x, spec, a, dim,
-                mesh,
-                # the switched pod axis always uses the paper's M-chain
-                # broadcast-composed schedule; intra-pod uses `mode`
-                "bcast" if pod_axis else mode,
-                n_chains,
-            )
-            entries = list(spec) + [None] * (x.ndim - len(spec))
+            # the switched pod axis always uses the paper's M-chain
+            # broadcast-composed schedule; intra-pod uses `mode`
+            full = _ag_local(jnp.moveaxis(x, dim, 0), a, "bcast" if a == "pod" else mode,
+                             min(n_chains, lax.axis_size(a)))
+            x = jnp.moveaxis(full, 0, dim)
     return x
 
 
+def _keep_axes(spec: P, keep) -> P:
+    """``spec`` with every mesh axis outside ``keep`` removed."""
+    out = []
+    for entry in spec:
+        axes = entry if isinstance(entry, tuple) else (entry,)
+        kept = tuple(a for a in axes if a is not None and a in keep)
+        out.append(kept if len(kept) > 1 else (kept[0] if kept else None))
+    return P(*out)
+
+
+@dataclass(frozen=True)
+class ParamGather:
+    """The ShardCtx.gather_params hook: the explicit gather of a one-layer
+    parameter slice, each leaf's layout re-derived from its name and shape.
+
+    Called on the slice, it is one ``shard_map`` per sharded leaf whose output
+    is replicated over dp. ``local`` is the same gather inside a region that is
+    already manual over the dp axes (``models/transformer.py``), where each
+    weight's cotangent stays a per-device partial until the ring reduce-
+    scatters it."""
+
+    mesh: Mesh
+    dp: tuple[str, ...]
+    mode: str
+    n_chains: int
+
+    def specs(self, tree):
+        """Each leaf's layout; ``tree`` holds global shapes."""
+        return jax.tree_util.tree_map_with_path(
+            lambda path, leaf: _leaf_spec(path, leaf, self.mesh, self.dp), tree)
+
+    def dp_specs(self, specs):
+        """The layouts over the dp axes alone: a dp-manual region's in_specs."""
+        return jax.tree.map(lambda s: _keep_axes(s, self.dp), specs)
+
+    def local(self, tree, specs):
+        """Gather each leaf's local shard inside a region manual over dp."""
+        return jax.tree.map(
+            lambda x, s: gather_leaf_local(x, s, self.dp, self.mode, self.n_chains),
+            tree, specs)
+
+    def __call__(self, tree):
+        def one(x, spec):
+            if all(e is None for e in spec):
+                return x
+            return jax.shard_map(
+                partial(gather_leaf_local, spec=spec, dp=self.dp, mode=self.mode,
+                        n_chains=self.n_chains),
+                mesh=self.mesh, in_specs=spec,
+                out_specs=_keep_axes(spec, set(self.mesh.axis_names) - set(self.dp)),
+                check_vma=False,
+            )(x)
+
+        return jax.tree.map(one, tree, self.specs(tree))
+
+
 def make_param_gather(mesh: Mesh, mesh_cfg: MeshConfig,
-                      coll: CollectiveConfig) -> Callable | None:
-    """The ShardCtx.gather_params hook: tree-maps the explicit gather over a
-    one-layer parameter slice (specs re-derived from leaf names/shapes)."""
+                      coll: CollectiveConfig) -> ParamGather | None:
+    """The gather hook of ``coll.fsdp_mode``; None for "xla" (XLA gathers)."""
     if coll.fsdp_mode == "xla":
         return None
-    dp = dp_axes(mesh_cfg)
     mode = {"mcast": "bidi", "mcast_ring": "ring", "mcast_bcast": "bcast"}.get(
         coll.fsdp_mode, "bidi"
     )
-
-    def gather(tree):
-        def one(path, leaf):
-            spec = _leaf_spec(path, leaf, mesh, dp)
-            if all(e is None for e in spec):
-                return leaf
-            return gather_leaf(leaf, spec, mesh, dp, mode, coll.n_chains)
-
-        return jax.tree_util.tree_map_with_path(one, tree)
-
-    return gather
+    return ParamGather(mesh, dp_axes(mesh_cfg), mode, coll.n_chains)
 
 
 # ----------------------------------------------------- flat-bucket utilities

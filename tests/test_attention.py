@@ -242,3 +242,48 @@ for shape in ((4, 1), (2, 2)):
     print("mesh", shape, "equal" if same else "differ")
 """, n_devices=4)
     assert "mesh (4, 1) equal" in out and "mesh (2, 2) equal" in out, out
+
+
+def test_fused_in_a_dp_manual_region_equals_no_mesh(multidev):
+    """Four CPU devices: the kernel called from inside a region already
+    manual over data, as the FSDP layer runs it (``transformer.py:
+    _scan_blocks_manual``), on data=4 and on data=2 x tp=2 with the heads
+    split, gives the unsharded result and gradients. Its own ``shard_map``
+    must then be manual over tp alone: were it manual over data again, its
+    transpose would sum each device's q, k and v gradients over data."""
+    out = multidev(
+        """
+import jax, jax.numpy as jnp, numpy as np
+from jax.sharding import PartitionSpec as P
+from repro.launch.mesh import make_mesh
+from repro.models import attention as A
+from repro.sharding import ShardCtx, use_ctx
+
+rng = np.random.default_rng(0)
+q, k, v = (jnp.asarray(rng.standard_normal(s), jnp.bfloat16)
+           for s in ((4, 256, 4, 64), (4, 256, 2, 64), (4, 256, 2, 64)))
+f = lambda q, k, v: A.fused_causal_attention(q, k, v, q_block=128, kv_block=128,
+                                             interpret=True)
+
+def run(f):
+    loss = lambda q, k, v: jnp.sum(f(q, k, v).astype(jnp.float32) ** 2)
+    return jax.jit(lambda q, k, v: (f(q, k, v), jax.grad(loss, (0, 1, 2))(q, k, v)))(q, k, v)
+
+base = run(f)
+for shape in ((4, 1), (2, 2)):
+    mesh = make_mesh(shape, ("data", "model"))
+
+    def local(q, k, v):
+        ctx = ShardCtx(mesh=jax.sharding.get_abstract_mesh(), dp_axes=(), tp_axis="model",
+                       shard_batch=False)
+        with use_ctx(ctx):
+            return f(q, k, v)
+
+    region = jax.shard_map(local, mesh=mesh, in_specs=P("data"), out_specs=P("data"),
+                           axis_names={"data"}, check_vma=False)
+    got = run(region)
+    same = all(np.array_equal(np.asarray(a, np.float32), np.asarray(b, np.float32))
+               for a, b in zip(jax.tree.leaves(got), jax.tree.leaves(base)))
+    print("mesh", shape, "equal" if same else "differ")
+""", n_devices=4)
+    assert "mesh (4, 1) equal" in out and "mesh (2, 2) equal" in out, out

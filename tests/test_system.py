@@ -38,6 +38,42 @@ print('ok', st.as_dict())
     )
 
 
+# TPU-compiled text: layouts hold parentheses, and an async start's result
+# is a tuple of its operand, its result and (for a permute) u32[] contexts
+_TPU_LOOP = 'metadata={op_name="jit(train_step)/jvp()/while/body/closed_call/%s"}'
+TPU_HLO = "\n".join([
+    "  %collective-permute-start.22 = (bf16[512,11008]{1,0:T(8,128)(2,1)}, "
+    "bf16[512,11008]{1,0:T(8,128)(2,1)S(1)}, u32[]{:S(2)}, u32[]{:S(2)}) "
+    "collective-permute-start(%get-tuple-element.8925), channel_id=1, "
+    "source_target_pairs={{0,1},{1,2},{2,3},{3,0}}, "
+    + _TPU_LOOP % "fsdp_gather/while/body/closed_call/ppermute",
+    "  %collective-permute-done.22 = bf16[512,11008]{1,0:T(8,128)(2,1)S(1)} "
+    "collective-permute-done(%collective-permute-start.22), "
+    + _TPU_LOOP % "fsdp_gather/while/body/closed_call/ppermute",
+    "  %all-reduce.42 = (f32[4096]{0:T(1024)S(1)}, f32[4096]{0:T(1024)S(1)}) "
+    "all-reduce(%get-tuple-element.8214, %copy-done.85), channel_id=1, "
+    "replica_groups={{0,1,2,3}}, use_global_device_ids=true, to_apply=%region_114.117, "
+    + _TPU_LOOP % "checkpoint/shard_map/psum",
+    "  %all-gather-start = (bf16[1024,64000]{1,0:T(8,128)(2,1)}, "
+    "bf16[4096,64000]{1,0:T(8,128)(2,1)}) all-gather-start(%p), channel_id=3, "
+    "replica_groups=[1,4]<=[4], dimensions={0}",
+    "  %all-gather-done = bf16[4096,64000]{1,0:T(8,128)(2,1)} "
+    "all-gather-done(%all-gather-start)",
+])
+
+
+def test_collective_stats_reads_tpu_text():
+    """A permute start counts what it delivers, once, inside the layer scan
+    and the ring's steps; a tuple all-reduce counts both of its results."""
+    from repro.launch.hlo_stats import collective_stats
+
+    st = collective_stats(TPU_HLO, 4, loop_chain=(8, 3))
+    assert dict(st.counts) == {"collective-permute": 1, "all-reduce": 1, "all-gather": 1}
+    assert st.per_device_bytes["collective-permute"] == 512 * 11008 * 2 * 8 * 3
+    assert st.per_device_bytes["all-reduce"] == 2 * 4096 * 4 * 2 * 3 // 4 * 8
+    assert st.per_device_bytes["all-gather"] == 4096 * 64000 * 2 * 3 // 4
+
+
 def test_loop_scaled_collectives(multidev):
     """Collectives inside a scan are multiplied by the loop-chain length."""
     multidev(

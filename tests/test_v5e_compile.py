@@ -129,13 +129,14 @@ def test_yi9b_l8_mcast_step_fits_2x2(topo):
     """The four-chip benchmark cell's whole train step (yi-9b at published
     widths, 8 layers, B=8 x S=2048, ``mcast`` gathers over data=4, full
     remat), as the benchmark assembles it, fits each chip; its ring sends
-    ZeRO-3's bytes less the replicated norms' share, and attention is the
+    ZeRO-3's bytes less the replicated norms' share, the ring's reduce-
+    scatter is each weight gradient's one reduction, and attention is the
     fused kernel inside ``shard_map``."""
     sys.path.insert(0, ROOT)
     from chipbench import cell as C
     from chipbench import zero3
     from chipbench.harness import Program
-    from repro.launch.hlo_stats import _loop_multiplier, _shape_bytes
+    from repro.launch.hlo_stats import _loop_multiplier, _shape_bytes, collective_stats
 
     cell = C.load("yi9b-l8-fsdp4-mcast")
     prog = Program(cell, topo.devices)
@@ -147,6 +148,8 @@ def test_yi9b_l8_mcast_step_fits_2x2(topo):
     used = (mem.argument_size_in_bytes + mem.output_size_in_bytes
             + mem.temp_size_in_bytes - mem.alias_size_in_bytes)
     assert 0 < used < V5E_HBM_BYTES, mem
+    # 7.215 GiB when XLA all-reduced every gathered weight's full gradient
+    assert used <= 7_746_799_104, used
 
     # each collective-permute-done holds what its start received; it runs
     # once per layer and ring step (the layer scan, and the ring's P - 1)
@@ -158,6 +161,14 @@ def test_yi9b_l8_mcast_step_fits_2x2(topo):
             sent += _shape_bytes(m.group(1)) * _loop_multiplier(body, (8, 3))
     norms = 3 * 3 * (2 * 8 * 4096 * 4) // 4
     assert sent == zero3.bytes_per_step(cell.config) - norms == 6_228_541_440
+
+    # inside the layer scan only the replicated norms (2 x 4096 float32 a
+    # layer) are all-reduced. Before the layer ran in a region manual over
+    # data, XLA all-reduced each gathered weight's full bf16 gradient ahead
+    # of the ring's reduce-scatter: 4,152,754,176 bytes per chip per step
+    scan = "\n".join(l for l in text.splitlines() if re.search(r"jvp\(\)\)?/while/body/", l))
+    reduced = collective_stats(scan, 4, loop_chain=(8, 3)).per_device_bytes["all-reduce"]
+    assert reduced == 2 * 4096 * 4 * 2 * 3 // 4 * 8 < 0.01 * 4_152_754_176
     assert "tpu_custom_call" in text
 
 
