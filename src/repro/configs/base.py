@@ -194,24 +194,18 @@ class MeshConfig:
 @dataclass(frozen=True)
 class CollectiveConfig:
     """Configuration of the paper's collective layer (core/collectives.py)."""
-    # fsdp_mode:
-    #   "xla"   — parameters sharded, XLA inserts all-gather/reduce-scatter (baseline)
-    #   "mcast" — explicit broadcast-composed allgather + bidirectional ring RS
-    #             on each weight along its sharded dim (the paper's schedule)
+    # fsdp_mode (sharding/fsdp.py), how each layer's weights are gathered:
+    #   "xla"         — weights sharded, XLA inserts all-gather/reduce-scatter
+    #   "mcast"       — the paper's bidirectional ring (Fig. 1's two trees)
+    #   "mcast_ring"  — the paper's ring, one direction
+    #   "mcast_bcast" — the paper's M-chain broadcast composition
     fsdp_mode: str = "xla"
     # number of parallel broadcast chains M (paper Appendix A). 2 == the two
     # ring directions of a full-duplex ICI link (Fig. 1's two trees).
     n_chains: int = 2
-    # chunk size (elements) for the pipelined broadcast; MTU analogue.
-    chunk_elems: int = 65_536
-    # direction-split concurrent AG/RS (Insight 2 analogue)
-    direction_split: bool = True
     # serve-time weight layout: replicate params over the dp axes (decode is
     # otherwise collective-bound on per-token FSDP gathers — §Perf knob)
     serve_params_replicated: bool = False
-    # explicit prefetch of layer i+1's FSDP gather during layer i's compute
-    # (mcast modes only; train path)
-    prefetch: bool = False
 
 
 @dataclass(frozen=True)

@@ -96,8 +96,7 @@ def run_cell(arch: str, shape_name: str, multi_pod: bool, *,
              mesh_shape: tuple[int, int] | None = None,
              serve_replicate: bool = False,
              moe_groups: int = 0,
-             kv_int8: bool = False,
-             prefetch: bool = False) -> dict:
+             kv_int8: bool = False) -> dict:
     """mesh_shape: regroup the same 256 chips as (dp, tp) — a §Perf knob
     (the mesh shape is a software view of the physical pod)."""
     t_start = time.monotonic()
@@ -114,7 +113,7 @@ def run_cell(arch: str, shape_name: str, multi_pod: bool, *,
         train=dataclasses.replace(run.train, grad_accum=grad_accum, remat=remat),
         collective=dataclasses.replace(
             run.collective, fsdp_mode=fsdp_mode,
-            serve_params_replicated=serve_replicate, prefetch=prefetch,
+            serve_params_replicated=serve_replicate,
         ),
     )
     if mesh_shape is not None:
@@ -221,7 +220,6 @@ def main():
     ap.add_argument("--serve-replicate", action="store_true")
     ap.add_argument("--moe-groups", type=int, default=0)
     ap.add_argument("--kv-int8", action="store_true")
-    ap.add_argument("--prefetch", action="store_true")
     ap.add_argument("--out", default=None)
     ap.add_argument("--no-hlo", action="store_true")
     args = ap.parse_args()
@@ -252,7 +250,6 @@ def main():
             remat=args.remat, collect_hlo=not args.no_hlo,
             mesh_shape=mesh_shape, serve_replicate=args.serve_replicate,
             moe_groups=args.moe_groups, kv_int8=args.kv_int8,
-            prefetch=args.prefetch,
         )
         status = "OK" if rec["ok"] else f"FAIL ({rec.get('error', '?')})"
         print(f"[dryrun] {arch} x {shape} multi_pod={args.multi_pod}: {status} "
@@ -277,8 +274,6 @@ def main():
                 variant.append(f"g{args.moe_groups}")
             if args.kv_int8:
                 variant.append("kvi8")
-            if args.prefetch:
-                variant.append("pf")
             if variant:
                 tag += "__" + "_".join(variant)
             with open(os.path.join(args.out, tag + ".json"), "w") as f:

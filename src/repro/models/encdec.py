@@ -94,8 +94,7 @@ def encode(params, cfg: ModelConfig, frames: jax.Array, remat="none") -> jax.Arr
         h = h + shard(layers.mlp_apply(bp["mlp"], h2, "gelu"), "dp", "sp", None)
         return h, None
 
-    fn = jax.checkpoint(body, prevent_cse=False) if remat == "full" else body
-    x, _ = jax.lax.scan(fn, x, params["enc_blocks"])
+    x, _ = jax.lax.scan(layers.remat_body(body, remat), x, params["enc_blocks"])
     return layers.rms_norm(x, params["enc_ln"], cfg.norm_eps)
 
 
@@ -154,8 +153,7 @@ def encdec_forward(params, cfg: ModelConfig, batch, *, want_cache=False, remat="
         h, kvs = _dec_block(bp, h, cfg, enc, want_kv=want_cache)
         return h, kvs
 
-    fn = jax.checkpoint(body, prevent_cse=False) if remat == "full" else body
-    x, kvs = jax.lax.scan(fn, x, params["dec_blocks"])
+    x, kvs = jax.lax.scan(layers.remat_body(body, remat), x, params["dec_blocks"])
     cache = None
     if want_cache:
         cache = {"k": kvs[0], "v": kvs[1], "xk": kvs[2], "xv": kvs[3]}

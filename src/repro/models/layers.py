@@ -149,7 +149,7 @@ def out_proj(p: Params, attn_out: jax.Array) -> jax.Array:
     return jnp.einsum("bse,ed->bsd", attn_out.reshape(b, s, h * hd), p["wo"].astype(attn_out.dtype))
 
 
-# ------------------------------------------------------------------ stacked init
+# ------------------------------------------------------------ stacked layers
 
 
 def stack_layer_init(rng, n_layers: int, init_one):
@@ -157,6 +157,21 @@ def stack_layer_init(rng, n_layers: int, init_one):
     rngs = jax.random.split(rng, n_layers)
     layers = [init_one(r) for r in rngs]
     return jax.tree.map(lambda *xs: jnp.stack(xs, axis=0), *layers)
+
+
+def remat_body(body, remat: str):
+    """A layer scan's ``body`` under the remat policy ``remat``: "none" keeps
+    every activation for the backward pass, "full" recomputes the whole
+    body there, "dots" saves the matmul outputs and recomputes the rest."""
+    if remat == "none":
+        return body
+    if remat == "full":
+        return jax.checkpoint(body, prevent_cse=False)
+    if remat == "dots":
+        return jax.checkpoint(
+            body, policy=jax.checkpoint_policies.checkpoint_dots, prevent_cse=False
+        )
+    raise ValueError(f"unknown remat policy {remat!r}: expected none, full or dots")
 
 
 def embed_init(rng, vocab: int, d_model: int, dtype) -> jax.Array:

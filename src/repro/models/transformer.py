@@ -16,7 +16,7 @@ import numpy as np
 from repro.configs.base import ModelConfig
 from repro.models import attention as attn
 from repro.models import layers, moe, rglru, rwkv
-from repro.sharding import get_ctx, shard, use_ctx
+from repro.sharding import get_ctx, shard
 from repro.sharding.ctx import maybe_gather_params
 
 Params = Any
@@ -194,13 +194,25 @@ def lm_logits(params, cfg: ModelConfig, x: jax.Array) -> jax.Array:
 
 
 def _scan_blocks(params, cfg, x, positions, *, want_kv, remat: str = "none"):
+    """The layer scan of dense, MoE and VLM blocks. Under the paper's FSDP
+    gathers, the train path of dense blocks with the batch split over dp
+    runs each layer, its gather included, in a region manual over dp
+    (``ParamGather.dp_manual``). Prefill (``want_kv``), MoE blocks (their
+    aux losses and expert axes) and a batch left whole gather through
+    ``maybe_gather_params``."""
     ctx = get_ctx()
-    gathered = _gathered_scan(ctx, cfg, want_kv)  # FSDP gathers by the paper's schedules
-    if gathered is not None:
-        return gathered(params, cfg, x, positions, remat=remat)
+    region = None
+    if (ctx.gather_params is not None and not want_kv and cfg.family != "moe"
+            and ctx.shard_batch):
+        def apply(bp, h, positions):
+            return dense_block_apply(bp, h, cfg, positions=positions, want_kv=False)[0]
+
+        region = ctx.gather_params.dp_manual(apply, params["blocks"])
 
     def body(carry, bp):
         h, aux_acc = carry
+        if region is not None:
+            return (region(bp, h, positions), aux_acc), None
         bp = maybe_gather_params(bp)  # FSDP gather (paper schedule) if active
         h, aux, kv = dense_block_apply(bp, h, cfg, positions=positions, want_kv=want_kv)
         aux_acc = {k: aux_acc.get(k, 0.0) + v for k, v in aux.items()} if aux else aux_acc
@@ -211,58 +223,10 @@ def _scan_blocks(params, cfg, x, positions, *, want_kv, remat: str = "none"):
         if cfg.family == "moe"
         else {}
     )
-    fn = body
-    if remat == "full":
-        fn = jax.checkpoint(body, prevent_cse=False)
-    elif remat == "dots":
-        fn = jax.checkpoint(
-            body, policy=jax.checkpoint_policies.checkpoint_dots, prevent_cse=False
-        )
-    (x, aux), kvs = jax.lax.scan(fn, (x, aux0), params["blocks"])
+    (x, aux), kvs = jax.lax.scan(layers.remat_body(body, remat), (x, aux0), params["blocks"])
     if cfg.family == "moe":
         aux = {k: v / cfg.num_layers for k, v in aux.items()}
     return x, aux, kvs
-
-
-def _scan_blocks_prefetch(params, cfg, x, positions, *, remat: str = "none"):
-    """Explicit compute/gather overlap (the paper's interleaved-collectives
-    discipline): the scan carry holds the ALREADY-GATHERED params of layer i;
-    each step first issues the gather of layer i+1 (a ppermute chain with no
-    data dependency on the block compute), then computes layer i — XLA's
-    scheduler runs the two concurrently. Train path only (no kv cache)."""
-    blocks = params["blocks"]
-    first = jax.tree.map(lambda l: l[0], blocks)
-    rest = jax.tree.map(lambda l: l[1:], blocks)
-    g0 = maybe_gather_params(first)
-    aux0 = (
-        {"moe_aux": 0.0, "moe_zloss": 0.0, "moe_drop_frac": 0.0}
-        if cfg.family == "moe"
-        else {}
-    )
-
-    def body(carry, bp_next_raw):
-        h, aux_acc, gathered = carry
-        g_next = maybe_gather_params(bp_next_raw)   # prefetch layer i+1
-        h, aux, _ = dense_block_apply(gathered, h, cfg, positions=positions,
-                                      want_kv=False)
-        aux_acc = {k: aux_acc.get(k, 0.0) + v for k, v in aux.items()} if aux else aux_acc
-        return (h, aux_acc, g_next), None
-
-    fn = body
-    if remat == "full":
-        fn = jax.checkpoint(body, prevent_cse=False)
-    elif remat == "dots":
-        fn = jax.checkpoint(
-            body, policy=jax.checkpoint_policies.checkpoint_dots, prevent_cse=False
-        )
-    (x, aux, g_last), _ = jax.lax.scan(fn, (x, aux0, g0), rest)
-    x, aux_l, _ = dense_block_apply(g_last, x, cfg, positions=positions,
-                                    want_kv=False)
-    if aux_l:
-        aux = {k: aux.get(k, 0.0) + v for k, v in aux_l.items()}
-    if cfg.family == "moe":
-        aux = {k: v / cfg.num_layers for k, v in aux.items()}
-    return x, aux, None
 
 
 def dense_forward(params, cfg: ModelConfig, batch, *, want_cache=False, remat="none"):
@@ -341,8 +305,7 @@ def rwkv_forward(params, cfg: ModelConfig, batch, *, want_cache=False, remat="no
         h, st = rwkv.rwkv_block_apply(bp, h, cfg, state=None, chunked=True)
         return h, (st if want_cache else None)
 
-    fn = jax.checkpoint(body, prevent_cse=False) if remat == "full" else body
-    x, sts = jax.lax.scan(fn, x, params["blocks"])
+    x, sts = jax.lax.scan(layers.remat_body(body, remat), x, params["blocks"])
     return x, {}, (sts if want_cache else None)
 
 
@@ -450,18 +413,16 @@ def hybrid_forward(params, cfg: ModelConfig, batch, *, want_cache=False, remat="
         ys = (st_a, st_b, kv) if want_cache else None
         return h, ys
 
-    fn = jax.checkpoint(body, prevent_cse=False) if remat == "full" else body
     xs = {k: params[k] for k in ("grp_rec_a", "grp_rec_b", "grp_attn")}
-    x, ys = jax.lax.scan(fn, x, xs)
+    x, ys = jax.lax.scan(layers.remat_body(body, remat), x, xs)
 
     def tail(h, tp_):
         tp_ = maybe_gather_params(tp_)
         h, st = _hybrid_rec_apply(tp_, h, cfg, None)
         return h, (st if want_cache else None)
 
-    tfn = jax.checkpoint(tail, prevent_cse=False) if remat == "full" else tail
     if nt:
-        x, tail_sts = jax.lax.scan(tfn, x, params["tail_rec"])
+        x, tail_sts = jax.lax.scan(layers.remat_body(tail, remat), x, params["tail_rec"])
     else:
         tail_sts = None
     cache = None
@@ -555,70 +516,3 @@ def hybrid_init_cache(cfg: ModelConfig, batch: int, seq_len: int, dtype):
         "attn_v": jnp.zeros((ng, batch, cfg.num_kv_heads, w, cfg.head_dim), dtype),
         "tail": rec_state(max(nt, 1)),
     }
-
-
-# ------------------------------------------------- gathered layers, dp-manual
-
-
-def _gathered_scan(ctx, cfg: ModelConfig, want_kv: bool):
-    """The layer scan that gathers each layer by the paper's schedules, or
-    None for ``_scan_blocks``' own (which gathers through
-    ``maybe_gather_params`` where a gather is set).
-
-    Prefetch keeps its scan. The train path of dense blocks with the batch
-    split over dp runs each layer in a dp-manual region
-    (``_scan_blocks_manual``). Prefill (``want_kv``), MoE blocks (their aux
-    losses and expert axes) and a batch left whole keep the plain scan."""
-    if ctx.gather_params is None or want_kv:
-        return None
-    if ctx.prefetch_params and cfg.num_layers > 1:
-        return _scan_blocks_prefetch
-    if cfg.family != "moe" and ctx.shard_batch:
-        return _scan_blocks_manual
-    return None
-
-
-def _scan_blocks_manual(params, cfg, x, positions, *, remat: str = "none"):
-    """Each layer's gather and ``dense_block_apply`` as one ``shard_map``
-    manual over the dp axes (tp stays automatic). The gathered weights are
-    per-device values, so each weight's cotangent stays a per-device partial
-    from its matmul to the ring reduce-scatter that transposes the gather:
-    the ring is the only reduction of a weight gradient. Replicated leaves
-    (the norms) come out of the transpose as small psums. Model code inside
-    sees the region's mesh and no dp axes, so ``shard`` and the attention
-    kernel's own ``shard_map`` work on the local batch."""
-    # imported here: a line added above would move the source lines that the
-    # HLO metadata, and so the compile cache's key, records for every step
-    import dataclasses
-
-    P = jax.sharding.PartitionSpec
-    ctx = get_ctx()
-    gather = ctx.gather_params
-    specs = gather.specs(jax.tree.map(
-        lambda l: jax.ShapeDtypeStruct(l.shape[1:], l.dtype), params["blocks"]))
-    rows = P(ctx.dp_axes)
-
-    def layer(bp, h, positions):
-        local = dataclasses.replace(ctx, mesh=jax.sharding.get_abstract_mesh(),
-                                    dp_axes=(), shard_batch=False, gather_params=None)
-        with use_ctx(local):
-            with jax.named_scope("fsdp_gather"):
-                bp = gather.local(bp, specs)
-            h, _, _ = dense_block_apply(bp, h, cfg, positions=positions, want_kv=False)
-        return h
-
-    region = jax.shard_map(layer, mesh=ctx.mesh, in_specs=(gather.dp_specs(specs), rows, P()),
-                           out_specs=rows, axis_names=set(ctx.dp_axes), check_vma=False)
-
-    def body(h, bp):
-        return region(bp, h, positions), None
-
-    fn = body
-    if remat == "full":
-        fn = jax.checkpoint(body, prevent_cse=False)
-    elif remat == "dots":
-        fn = jax.checkpoint(
-            body, policy=jax.checkpoint_policies.checkpoint_dots, prevent_cse=False
-        )
-    x, _ = jax.lax.scan(fn, x, params["blocks"])
-    return x, {}, None

@@ -48,7 +48,6 @@ def make_ctx(run: RunConfig, mesh: Mesh | None, *, for_decode: bool = False) -> 
         shard_batch=shard_batch,
         seq_parallel=not for_decode,
         gather_params=gather,
-        prefetch_params=run.collective.prefetch and gather is not None,
     )
 
 

@@ -32,9 +32,6 @@ class ShardCtx:
     # FSDP "mcast" mode: explicit per-layer param gather using the paper's
     # collectives (sharding/fsdp.make_param_gather); None = XLA-inserted.
     gather_params: object = None
-    # explicit compute/gather overlap: prefetch layer i+1's params while
-    # computing layer i (the paper's interleaved-collectives discipline)
-    prefetch_params: bool = False
 
 
 _CTX: list[ShardCtx] = [ShardCtx(mesh=None)]

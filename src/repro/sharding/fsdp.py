@@ -1,16 +1,16 @@
 """FSDP (ZeRO-3) parameter gathering with the paper's collectives.
 
-Two modes (CollectiveConfig.fsdp_mode):
+Four modes (CollectiveConfig.fsdp_mode):
 
-  "xla"   — parameters stay sharded (specs.py); XLA/GSPMD inserts all-gather
-            before use and reduce-scatter for grads. Baseline.
-  "mcast" — the paper's schedule, explicit: inside the layer scan each
-            dp-sharded weight is gathered by a shard_map ppermute kernel
-            (bidirectional ring = Fig. 1's two trees, or the general M-chain
-            broadcast composition). The AD transpose of the gather is the
-            matching ring reduce-scatter on the opposite direction, i.e. the
-            Insight-2 direction split of grad traffic vs weight traffic
-            falls out of the schedule for free.
+  "xla"         — weights stay sharded (specs.py); XLA inserts the gathers.
+  "mcast"       — the bidirectional ring, Fig. 1's two trees.
+  "mcast_ring"  — the one-direction ring.
+  "mcast_bcast" — the M-chain broadcast composition (Appendix A).
+
+In the three paper modes each dp-sharded weight is gathered inside the layer
+scan by ``shard_map`` ppermutes. The gather's AD transpose is the matching
+ring reduce-scatter on the opposite direction, so the Insight-2 direction
+split of grad traffic vs weight traffic falls out of the schedule for free.
 
 On the multi-pod mesh the gather is hierarchical: ICI ring over "data" inside
 the pod, then the M-chain broadcast composition over the switched "pod" axis —
@@ -18,7 +18,7 @@ the axis where the paper's multicast protocol literally applies (DESIGN.md §2).
 """
 from __future__ import annotations
 
-from dataclasses import dataclass
+import dataclasses
 from functools import partial
 
 import jax
@@ -29,6 +29,7 @@ from jax.sharding import PartitionSpec as P
 
 from repro.configs.base import CollectiveConfig, MeshConfig
 from repro.core import collectives as C
+from repro.sharding.ctx import get_ctx, use_ctx
 from repro.sharding.specs import _leaf_spec, dp_axes
 
 
@@ -71,14 +72,14 @@ def _keep_axes(spec: P, keep) -> P:
     return P(*out)
 
 
-@dataclass(frozen=True)
+@dataclasses.dataclass(frozen=True)
 class ParamGather:
     """The ShardCtx.gather_params hook: the explicit gather of a one-layer
     parameter slice, each leaf's layout re-derived from its name and shape.
 
     Called on the slice, it is one ``shard_map`` per sharded leaf whose output
-    is replicated over dp. ``local`` is the same gather inside a region that is
-    already manual over the dp axes (``models/transformer.py``), where each
+    is replicated over dp. ``dp_manual`` runs a whole layer, its gather
+    included, inside one region manual over the dp axes instead, where each
     weight's cotangent stays a per-device partial until the ring reduce-
     scatters it."""
 
@@ -87,20 +88,10 @@ class ParamGather:
     mode: str
     n_chains: int
 
-    def specs(self, tree):
+    def _specs(self, tree):
         """Each leaf's layout; ``tree`` holds global shapes."""
         return jax.tree_util.tree_map_with_path(
             lambda path, leaf: _leaf_spec(path, leaf, self.mesh, self.dp), tree)
-
-    def dp_specs(self, specs):
-        """The layouts over the dp axes alone: a dp-manual region's in_specs."""
-        return jax.tree.map(lambda s: _keep_axes(s, self.dp), specs)
-
-    def local(self, tree, specs):
-        """Gather each leaf's local shard inside a region manual over dp."""
-        return jax.tree.map(
-            lambda x, s: gather_leaf_local(x, s, self.dp, self.mode, self.n_chains),
-            tree, specs)
 
     def __call__(self, tree):
         def one(x, spec):
@@ -114,7 +105,39 @@ class ParamGather:
                 check_vma=False,
             )(x)
 
-        return jax.tree.map(one, tree, self.specs(tree))
+        return jax.tree.map(one, tree, self._specs(tree))
+
+    def dp_manual(self, apply, blocks):
+        """``(bp, h, positions) -> h``: one layer of the stacked ``blocks``,
+        its gather and ``apply(bp, h, positions)``, as one ``shard_map``
+        manual over the dp axes (tp stays automatic), ``h`` split over dp by
+        rows. The gathered weights are per-device values, so each weight's
+        cotangent stays a per-device partial from its matmul to the ring
+        reduce-scatter that transposes the gather: the ring is the only
+        reduction of a weight gradient. Replicated leaves (the norms) come
+        out of the transpose as small psums. ``apply`` sees the region's
+        mesh, no dp axes and no gather, so ``shard`` and a ``shard_map``
+        nested in it work on the local batch."""
+        ctx = get_ctx()
+        specs = self._specs(jax.tree.map(
+            lambda l: jax.ShapeDtypeStruct(l.shape[1:], l.dtype), blocks))
+        rows = P(self.dp)
+
+        def layer(bp, h, positions):
+            local = dataclasses.replace(ctx, mesh=jax.sharding.get_abstract_mesh(),
+                                        dp_axes=(), shard_batch=False, gather_params=None)
+            with use_ctx(local):
+                with jax.named_scope("fsdp_gather"):
+                    bp = jax.tree.map(lambda x, s: gather_leaf_local(
+                        x, s, self.dp, self.mode, self.n_chains), bp, specs)
+                return apply(bp, h, positions)
+
+        dp_specs = jax.tree.map(lambda s: _keep_axes(s, self.dp), specs)
+        return jax.shard_map(layer, mesh=self.mesh, in_specs=(dp_specs, rows, P()),
+                             out_specs=rows, axis_names=set(self.dp), check_vma=False)
+
+
+_SCHEDULES = {"mcast": "bidi", "mcast_ring": "ring", "mcast_bcast": "bcast"}
 
 
 def make_param_gather(mesh: Mesh, mesh_cfg: MeshConfig,
@@ -122,35 +145,7 @@ def make_param_gather(mesh: Mesh, mesh_cfg: MeshConfig,
     """The gather hook of ``coll.fsdp_mode``; None for "xla" (XLA gathers)."""
     if coll.fsdp_mode == "xla":
         return None
-    mode = {"mcast": "bidi", "mcast_ring": "ring", "mcast_bcast": "bcast"}.get(
-        coll.fsdp_mode, "bidi"
-    )
-    return ParamGather(mesh, dp_axes(mesh_cfg), mode, coll.n_chains)
-
-
-# ----------------------------------------------------- flat-bucket utilities
-
-
-def flatten_bucket(tree, pad_to: int = 1):
-    """Flatten a pytree into one contiguous padded fp bucket (the paper's
-    collectives operate on flat byte buffers; used by benchmarks/examples)."""
-    leaves, treedef = jax.tree.flatten(tree)
-    flat = jnp.concatenate([l.reshape(-1).astype(jnp.float32) for l in leaves])
-    n = flat.shape[0]
-    padded = -(-n // pad_to) * pad_to
-    if padded != n:
-        flat = jnp.pad(flat, (0, padded - n))
-
-    shapes = [(l.shape, l.dtype) for l in leaves]
-
-    def unflatten(buf):
-        out, off = [], 0
-        for shape, dtype in shapes:
-            k = 1
-            for s in shape:
-                k *= s
-            out.append(buf[off : off + k].reshape(shape).astype(dtype))
-            off += k
-        return jax.tree.unflatten(treedef, out)
-
-    return flat, unflatten
+    if coll.fsdp_mode not in _SCHEDULES:
+        raise ValueError(f"unknown fsdp_mode {coll.fsdp_mode!r}: expected xla or one of "
+                         f"{', '.join(_SCHEDULES)}")
+    return ParamGather(mesh, dp_axes(mesh_cfg), _SCHEDULES[coll.fsdp_mode], coll.n_chains)

@@ -246,8 +246,8 @@ for shape in ((4, 1), (2, 2)):
 
 def test_fused_in_a_dp_manual_region_equals_no_mesh(multidev):
     """Four CPU devices: the kernel called from inside a region already
-    manual over data, as the FSDP layer runs it (``transformer.py:
-    _scan_blocks_manual``), on data=4 and on data=2 x tp=2 with the heads
+    manual over data, as the FSDP layer runs it (``fsdp.py:
+    ParamGather.dp_manual``), on data=4 and on data=2 x tp=2 with the heads
     split, gives the unsharded result and gradients. Its own ``shard_map``
     must then be manual over tp alone: were it manual over data again, its
     transpose would sum each device's q, k and v gradients over data."""
